@@ -12,7 +12,6 @@ from repro.core.compression import sparsify_mask
 from repro.kernels import ops
 from repro.kernels.flash_decode import flash_decode, flash_decode_paged
 from repro.kernels.ref import block_topk_ref
-from repro.kernels.scatter_agg import scatter_aggregate
 from repro.models.attention import decode_attention
 
 
@@ -62,25 +61,6 @@ def _flash_decode_rows():
     return rows
 
 
-def _scatter_agg_row():
-    """Fused aggregation vs the densify→scatter-add chain (D=8 packets)."""
-    D, k, n = 8, 1024, 1 << 18
-    kv, ki = jax.random.split(jax.random.PRNGKey(7))
-    vals = jax.random.normal(kv, (D, k))
-    idx = jnp.stack([jax.random.permutation(kk, n)[:k].astype(jnp.int32)
-                     for kk in jax.random.split(ki, D)])
-    fused = jax.jit(lambda v, i: scatter_aggregate(v, i, n))
-    chain = jax.jit(lambda v, i: jnp.zeros((n,), v.dtype)
-                    .at[i.reshape(-1)].add(v.reshape(-1)))
-    exact = bool(jnp.all(fused(vals, idx) == chain(vals, idx)))
-    us_f = timeit(lambda: jax.block_until_ready(fused(vals, idx)), n=3)
-    us_c = timeit(lambda: jax.block_until_ready(chain(vals, idx)), n=3)
-    emit("kernel_scatter_agg_8x1k", us_f,
-         f"bit_exact={exact};chain_us={us_c:.0f}")
-    return {"kernel": "scatter_agg", "devices": D, "k": k, "n": n,
-            "kernel_us": us_f, "chain_us": us_c, "bit_exact": exact}
-
-
 def main():
     n = 1 << 20  # ~1M grads (ResNet-scale slice)
     flat = jax.random.normal(jax.random.PRNGKey(0), (n,))
@@ -110,7 +90,6 @@ def main():
     rows.append({"kernel": "fused_sgdm", "n": n, "us": us,
                  "mode": "interpret(cpu-correctness)"})
     rows.extend(_flash_decode_rows())
-    rows.append(_scatter_agg_row())
     write_json_artifact("artifacts/perf/kernels.json", {"rows": rows})
 
 

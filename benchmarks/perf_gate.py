@@ -155,10 +155,6 @@ TOLERANCES = {
         tol_frac=0.0, abs_tol=1e-3, direction="lower",
         note="pallas flash-attention prefill vs the chunked jax path, worst "
              "case over causal and SWA kinds with a q_offset chunk"),
-    "kernel_scatter_agg_max_err": dict(
-        tol_frac=0.0, abs_tol=0.0, direction="lower",
-        note="fused scatter_aggregate vs densify→scatter-add with "
-             "cross-device duplicate indices: pinned bit-exact (0.0)"),
 }
 
 
@@ -383,7 +379,6 @@ def collect_kernels():
     import jax.numpy as jnp
 
     from repro.kernels.flash_decode import flash_decode, flash_decode_paged
-    from repro.kernels.scatter_agg import scatter_aggregate
     from repro.models.attention import chunked_attention, decode_attention
 
     key = jax.random.PRNGKey(GATE_SEED)
@@ -418,19 +413,9 @@ def collect_kernels():
                                   interpret=True)
         err_f = max(err_f, float(jnp.max(jnp.abs(out_a - ref_a))))
 
-    D, kk, n = 4, 16, 512
-    vals = jax.random.normal(ks[5], (D, kk))
-    idx = jnp.stack([jax.random.permutation(kx, n)[:kk].astype(jnp.int32)
-                     for kx in jax.random.split(ks[6], D)])
-    idx = idx.at[2, :5].set(idx[0, :5])      # cross-device duplicates
-    ref_g = (jnp.zeros((n,), vals.dtype)
-             .at[idx.reshape(-1)].add(vals.reshape(-1)))
-    err_s = float(jnp.max(jnp.abs(
-        scatter_aggregate(vals, idx, n, interpret=True) - ref_g)))
     return {
         "kernel_decode_max_err": max(err_c, err_p),
         "kernel_prefill_flash_max_err": err_f,
-        "kernel_scatter_agg_max_err": err_s,
     }
 
 

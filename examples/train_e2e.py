@@ -7,7 +7,6 @@ Uses the ScaDLES-integrated trainer (per-sample rate weights + linear LR
 scaling active) on the synthetic bigram LM stream; checkpoints at the end.
 """
 import argparse
-import sys
 
 from repro.launch import train
 
@@ -20,15 +19,15 @@ def main():
     args = ap.parse_args()
     if args.full:
         steps = args.steps or 200
-        sys.argv = ["train", "--arch", "xlstm-125m", "--steps", str(steps),
-                    "--batch", "8", "--seq", "256", "--scadles",
-                    "--ckpt", "artifacts/ckpt"]
+        argv = ["--arch", "xlstm-125m", "--steps", str(steps),
+                "--batch", "8", "--seq", "256", "--scadles",
+                "--ckpt", "artifacts/ckpt"]
     else:
         steps = args.steps or 60
-        sys.argv = ["train", "--arch", "xlstm-125m", "--reduced",
-                    "--steps", str(steps), "--batch", "16", "--seq", "128",
-                    "--scadles", "--ckpt", "artifacts/ckpt"]
-    train.main()
+        argv = ["--arch", "xlstm-125m", "--reduced",
+                "--steps", str(steps), "--batch", "16", "--seq", "128",
+                "--scadles", "--ckpt", "artifacts/ckpt"]
+    train.main(argv)
 
 
 if __name__ == "__main__":

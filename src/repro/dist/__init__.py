@@ -13,8 +13,6 @@ Four pieces:
 * ``calibrate``    — lowers the dense/compressed DDP programs and turns their
   parsed collective wire bytes into the fleet engine's comm-bytes model.
 """
-import repro.compat  # noqa: F401  (jax 0.4.x shims; must precede jax use)
-
 from repro.dist import hlo_analysis, hlo_cost, sharding  # noqa: F401
 from repro.dist.hlo_analysis import (CollectiveOp, collective_bytes,  # noqa: F401
                                      model_flops, roofline)

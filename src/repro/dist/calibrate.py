@@ -25,8 +25,6 @@ import subprocess
 import sys
 from typing import Optional
 
-import repro.compat  # noqa: F401
-
 
 def ring_wire_bytes(n_devices: int, floats_on_wire: float) -> float:
     """The legacy analytic model: per-device ring all-reduce bytes."""

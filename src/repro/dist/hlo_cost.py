@@ -85,6 +85,10 @@ class Instr:
     out_shapes: List[Tuple[str, Tuple[int, ...]]]
     operand_text: str
     attrs: str
+    # operand shapes, resolved through the computation's names when the
+    # text prints bare operand names (current XLA) rather than typed ones
+    operand_shapes: List[Tuple[str, Tuple[int, ...]]] = dataclasses.field(
+        default_factory=list)
 
 
 @dataclasses.dataclass
@@ -176,10 +180,19 @@ def _parse_instr(line: str) -> Optional[Instr]:
                  operand_text=operand_text, attrs=attrs)
 
 
+def _resolve_operands(instr: Instr, names: Dict[str, list]) -> None:
+    shapes = _shapes_of(instr.operand_text)
+    if not shapes:
+        for ref in re.findall(r"%([\w.\-$]+)", instr.operand_text):
+            shapes.extend(names.get(ref, []))
+    instr.operand_shapes = shapes
+
+
 def parse_module(hlo_text: str) -> Module:
     comps: Dict[str, List[Instr]] = {}
     entry = ""
     current: Optional[List[Instr]] = None
+    names: Dict[str, list] = {}
     for raw in hlo_text.splitlines():
         line = raw.rstrip()
         if not line or line.startswith("HloModule"):
@@ -192,6 +205,7 @@ def parse_module(hlo_text: str) -> Module:
             if nm is None:
                 continue
             current = comps.setdefault(nm.group(1), [])
+            names = {}
             if is_entry:
                 entry = nm.group(1)
             continue
@@ -201,6 +215,8 @@ def parse_module(hlo_text: str) -> Module:
         if current is not None:
             instr = _parse_instr(line)
             if instr is not None:
+                _resolve_operands(instr, names)
+                names[instr.name] = instr.out_shapes
                 current.append(instr)
     if not entry and comps:   # fall back: last computation is usually entry
         entry = list(comps)[-1]
@@ -267,7 +283,7 @@ def collective_of(instr: Instr, module: Module) -> Optional[CollectiveOp]:
 
 def _dot_flops(instr: Instr) -> float:
     out = sum(_elems(sh) for _, sh in instr.out_shapes)
-    operands = _shapes_of(instr.operand_text)
+    operands = instr.operand_shapes
     if not operands:
         return 0.0
     lhs_dims = operands[0][1]
@@ -281,7 +297,7 @@ def _dot_flops(instr: Instr) -> float:
 
 def _conv_flops(instr: Instr) -> float:
     out = sum(_elems(sh) for _, sh in instr.out_shapes)
-    operands = _shapes_of(instr.operand_text)
+    operands = instr.operand_shapes
     if len(operands) < 2:
         return 0.0
     kernel = operands[1][1]
@@ -311,7 +327,7 @@ def _instr_cost(instr: Instr, module: Module,
     op = instr.opcode
     out_elems = sum(_elems(sh) for _, sh in instr.out_shapes)
     out_bytes = _bytes(instr.out_shapes)
-    operand_bytes = _bytes(_shapes_of(instr.operand_text))
+    operand_bytes = _bytes(instr.operand_shapes)
     io_bytes = operand_bytes + out_bytes
 
     if op in _FREE:
@@ -354,13 +370,13 @@ def _instr_cost(instr: Instr, module: Module,
     if op == "convolution":
         return Cost(flops=_conv_flops(instr), bytes=io_bytes)
     if op == "reduce":
-        in_elems = sum(_elems(sh) for _, sh in _shapes_of(instr.operand_text))
+        in_elems = sum(_elems(sh) for _, sh in instr.operand_shapes)
         return Cost(flops=float(max(in_elems - out_elems, 0)), bytes=io_bytes)
     if op == "reduce-window":
         return Cost(flops=float(out_elems * max(_window_elems(instr.attrs) - 1, 1)),
                     bytes=io_bytes)
     if op == "scatter":
-        operands = _shapes_of(instr.operand_text)
+        operands = instr.operand_shapes
         upd = _elems(operands[-1][1]) if operands else 0
         return Cost(flops=float(upd), bytes=io_bytes)
     if op in _TRANSCENDENTAL:

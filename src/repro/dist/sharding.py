@@ -25,8 +25,6 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Dict, Optional, Tuple
 
-import repro.compat  # noqa: F401
-
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 

@@ -1,3 +1,13 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels for the training and serving hot paths."""
+from __future__ import annotations
+
+from typing import Optional
+
+
+def resolve_interpret(interpret: Optional[bool] = None) -> bool:
+    """Pallas interpret flag: None lets the platform decide (compiled on a
+    TPU, the interpreter everywhere else); a bool is taken as given."""
+    if interpret is not None:
+        return bool(interpret)
+    import jax
+    return jax.default_backend() != "tpu"

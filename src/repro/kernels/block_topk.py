@@ -21,6 +21,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 N_BISECT = 20
 DEFAULT_BLOCK = 1024     # lanes-aligned (8 sublanes x 128 lanes)
@@ -64,7 +67,7 @@ def _block_topk_call(g2d: jnp.ndarray, k: int, interpret: bool):
                    pl.BlockSpec((tile, 1), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((n_blocks, block), g2d.dtype),
                    jax.ShapeDtypeStruct((n_blocks, 1), jnp.int32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(g2d)
 
 
@@ -89,11 +92,11 @@ _block_topk_vjp.defvjp(_block_topk_fwd, _block_topk_bwd)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
-def block_topk(g2d: jnp.ndarray, k: int, interpret: bool = True):
+def block_topk(g2d: jnp.ndarray, k: int, interpret: bool = None):
     """g2d (n_blocks, block_size) -> (sparsified g2d, counts (n_blocks, 1)).
 
-    ``k`` survivors per block.  ``interpret=True`` executes the kernel body in
-    Python on CPU (validation mode); on TPU pass interpret=False.
+    ``k`` survivors per block.  ``interpret=None`` compiles on a TPU and
+    runs the interpreter elsewhere (``repro.kernels.resolve_interpret``).
     Differentiable: the VJP is a straight-through mask over survivors, so the
     compressed DDP program stays differentiable end-to-end.
     """
@@ -118,7 +121,7 @@ def _fused_sgdm_kernel(p_ref, m_ref, g_ref, lr_ref, out_p_ref, out_m_ref, *,
 @functools.partial(jax.jit,
                    static_argnames=("momentum", "weight_decay", "interpret"))
 def fused_sgdm(p2d, m2d, g2d, lr, momentum: float = 0.9,
-               weight_decay: float = 0.0, interpret: bool = True):
+               weight_decay: float = 0.0, interpret: bool = None):
     """Fused SGD-momentum over (rows, block) tiles; one pass over HBM."""
     n_blocks, block = p2d.shape
     tile = min(TILE_BLOCKS, n_blocks)
@@ -132,10 +135,10 @@ def fused_sgdm(p2d, m2d, g2d, lr, momentum: float = 0.9,
         in_specs=[pl.BlockSpec((tile, block), lambda i: (i, 0)),
                   pl.BlockSpec((tile, block), lambda i: (i, 0)),
                   pl.BlockSpec((tile, block), lambda i: (i, 0)),
-                  pl.BlockSpec(memory_space=pl.ANY)],
+                  pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=[pl.BlockSpec((tile, block), lambda i: (i, 0)),
                    pl.BlockSpec((tile, block), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct(p2d.shape, p2d.dtype),
                    jax.ShapeDtypeStruct(m2d.shape, jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(p2d, m2d, g2d, lr_arr)

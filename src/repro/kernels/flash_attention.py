@@ -9,8 +9,9 @@ path, which doubles as this kernel's oracle (GQA handled by the wrapper via
 kv-head indexing).  Forward only: training uses the custom-VJP JAX path for
 the backward; serving prefill is where this kernel pays off.
 
-Validated in interpret mode on CPU (tests/test_kernels_flash.py); compile
-with interpret=False on TPU.
+Validated in interpret mode on CPU (tests/test_kernels_flash.py) and
+compiled for v5e in tests/test_tpu_compile.py; ``interpret=None`` lets the
+platform decide.
 """
 from __future__ import annotations
 
@@ -20,9 +21,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 NEG_INF = -1e30
 DEFAULT_BQ = 128
 DEFAULT_BK = 128
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *, kind: str, window: int,
@@ -36,10 +40,10 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *, kind: str, window: int,
 
     def body(s_idx, carry):
         m, l, acc = carry
-        blk = (pl.dslice(0, 1), pl.dslice(s_idx * bk, bk), slice(None))
-        k = pl.load(k_ref, blk).reshape(bk, hd).astype(jnp.float32)
-        v = pl.load(v_ref, blk).reshape(bk, hd).astype(jnp.float32)
-        s = q @ k.T                                     # (bq, bk)
+        k = k_ref[0, pl.ds(s_idx * bk, bk), :].astype(jnp.float32)
+        v = v_ref[0, pl.ds(s_idx * bk, bk), :].astype(jnp.float32)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                precision=_HIGHEST)     # (bq, bk)
         kpos = s_idx * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
         if kind in ("causal", "swa"):
             mask = kpos <= qpos
@@ -53,7 +57,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *, kind: str, window: int,
         c1 = jnp.exp(m - m_new)
         c2 = jnp.exp(m_b - m_new)
         return (m_new, l * c1 + l_b * c2,
-                acc * c1 + (p @ v) * c2)
+                acc * c1 + jnp.dot(p, v, precision=_HIGHEST) * c2)
 
     m0 = jnp.full((bq, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((bq, 1), jnp.float32)
@@ -66,7 +70,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *, kind: str, window: int,
                                              "q_offset", "interpret"))
 def flash_attention_fwd(q, k, v, *, kind: str = "causal", window: int = 0,
                         bq: int = DEFAULT_BQ, bk: int = DEFAULT_BK,
-                        q_offset: int = 0, interpret: bool = True):
+                        q_offset: int = 0, interpret: bool = None):
     """q (bh, sq, hd); k/v (bh, sk, hd) — heads pre-flattened/pre-repeated.
 
     Returns (bh, sq, hd).  bq/bk are the VMEM tile sizes (128-aligned for the
@@ -91,13 +95,13 @@ def flash_attention_fwd(q, k, v, *, kind: str = "causal", window: int = 0,
                   pl.BlockSpec((1, sk, hd), lambda h, i: (h, 0, 0))],
         out_specs=pl.BlockSpec((1, bq, hd), lambda h, i: (h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq, hd), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)
 
 
 def flash_attention(q, k, v, *, kind: str = "causal", window: int = 0,
                     bq: int = DEFAULT_BQ, bk: int = DEFAULT_BK,
-                    q_offset: int = 0, interpret: bool = True):
+                    q_offset: int = 0, interpret: bool = None):
     """Convenience GQA wrapper: q (b, sq, h, hd), k/v (b, sk, kv, hd)."""
     b, sq, h, hd = q.shape
     _, sk, kvh, _ = k.shape

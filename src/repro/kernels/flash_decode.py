@@ -1,9 +1,7 @@
 """Pallas TPU kernel family: single-token flash-decode over slot caches.
 
-After the PR-8 serving refactor, single-token decode over the continuous-
-batching caches is the serving hot path — and it ran XLA-default attention
-(`models/attention.py::decode_attention`) over a gathered contiguous view.
-This kernel family reads the repo's cache layouts *directly*:
+Single-token decode over the continuous-batching caches is the serving hot
+path.  This kernel family reads the repo's cache layouts *directly*:
 
 * **contiguous** (`flash_decode`) — fixed-slot `(b, S, kv, hd)` K/V rows and
   SWA ring buffers share one kernel: the ring's scrambled storage order is
@@ -12,25 +10,27 @@ This kernel family reads the repo's cache layouts *directly*:
   both the mixed-age fixed case (`kv_len = pos+1`) and the wrapped ring
   (`kv_len = S` once `pos >= S`).
 * **paged** (`flash_decode_paged`) — page pools `(rows, page, kv, hd)`
-  behind per-slot int32 block tables: the kernel resolves `pool[bt[slot,
-  page]]` *inside* the streaming loop, so the materialised contiguous
-  gather (`pool[bt].reshape(...)` — a full cache copy per decode step) in
+  behind per-slot int32 block tables: the block table is scalar-prefetched
+  and each grid step's K/V block index map resolves `pool[bt[slot, page]]`,
+  so the materialised contiguous gather (`pool[bt].reshape(...)`) in
   `models/decode.py::_block_decode` disappears from the paged hot path.
   Scratch-page-evicted slots ride the batch safely: their reads are
   kv_len-masked exactly like the jnp path.
 
-Grid covers (slot, kv-head); each program streams K/V blocks with an
-online-softmax `(m, l, acc)` carry — the blockwise structure of
-`kernels/flash_attention.py` specialised to one query token per slot (the
-(g, hd) grouped-query tile attends against (bk, hd) key blocks).  Softmax
-statistics accumulate in fp32 regardless of cache dtype, matching
-`decode_attention`'s `preferred_element_type` discipline, so kernel-vs-
-oracle equality holds to float tolerance (tests/test_kernels_decode.py).
+Layout.  K/V are viewed as `(…, S, kv*hd)` (a free reshape), so every block
+keeps whole rows in its minor dim and the TPU's (8, 128) tiling never splits
+a head.  The grouped-query tile is laid out block-diagonally: row
+`(head, j)` of the `(kv*g, kv*hd)` query holds that head's query in its own
+head's lanes and zeros elsewhere, so one matmul against a `(bk, kv*hd)` key
+block gives every head's scores, and `p @ v` leaves each row's answer in its
+own head's lanes, which the wrapper picks out.
 
-Dispatched from `models/decode.py` behind ``RunCtx.decode_backend =
-"pallas"`` (interpret mode on CPU for validation, compiled on TPU —
-``RunCtx.kernel_interpret`` overrides the autodetect), so `serve.SlotRunner`
-and the multi-lane `Scheduler` ride the kernels transparently.
+Grid is (slot, key block); the key-block axis carries the online-softmax
+`(m, l, acc)` state in VMEM scratch and writes the output on its last step.
+Blocks past a slot's `kv_len` skip their compute, and their index map
+repeats the last valid block so no new DMA is issued.  Softmax statistics
+accumulate in fp32 regardless of cache dtype, matching `decode_attention`'s
+`preferred_element_type` discipline (tests/test_kernels_decode.py).
 """
 from __future__ import annotations
 
@@ -40,89 +40,114 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 NEG_INF = -1e30
 DEFAULT_BK = 128
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-def _online_update(carry, s, v):
-    """One online-softmax step: s (g, bk) fp32 scores, v (bk, hd) fp32."""
-    m, l, acc = carry
-    m_b = jnp.max(s, axis=-1, keepdims=True)
-    p = jnp.exp(s - m_b)
-    l_b = jnp.sum(p, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m, m_b)
-    c1 = jnp.exp(m - m_new)
-    c2 = jnp.exp(m_b - m_new)        # 0 for an all-masked block: no leakage
-    return m_new, l * c1 + l_b * c2, acc * c1 + (p @ v) * c2
-
-
-def _finish(o_ref, carry):
-    _, l, acc = carry
-    o_ref[0, 0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-
-
-def _decode_kernel(q_ref, k_ref, v_ref, kvl_ref, o_ref, *, bk: int, nk: int,
-                   scale: float):
-    """Contiguous caches. q_ref (1, 1, g, hd); k/v_ref (1, S, 1, hd);
-    kvl_ref whole (b,) int32; o_ref (1, 1, g, hd).  Grid (slot, kv-head)."""
-    g, hd = q_ref.shape[2], q_ref.shape[3]
-    slot = pl.program_id(0)
-    q = q_ref[0, 0].astype(jnp.float32) * scale          # (g, hd)
+def _decode_kernel(bt_ref, kvl_ref, q_ref, k_ref, v_ref, o_ref, m_sc, l_sc,
+                   acc_sc, *, bk: int, scale: float):
+    """q_ref (1, R, kv*hd) block-diagonal query rows; k/v_ref (1, bk, kv*hd)
+    one key block; o_ref (1, R, kv*hd).  Grid (slot, key block)."""
+    del bt_ref                       # consumed by the index maps only
+    slot, c = pl.program_id(0), pl.program_id(1)
     kv_len = kvl_ref[slot]
 
-    def body(i, carry):
-        blk = (pl.dslice(0, 1), pl.dslice(i * bk, bk), pl.dslice(0, 1),
-               slice(None))
-        k = pl.load(k_ref, blk).reshape(bk, hd).astype(jnp.float32)
-        v = pl.load(v_ref, blk).reshape(bk, hd).astype(jnp.float32)
-        s = q @ k.T                                      # (g, bk)
-        kpos = i * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+    @pl.when(c == 0)
+    def _init():
+        m_sc[...] = jnp.full(m_sc.shape, NEG_INF, jnp.float32)
+        l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
+        acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
+
+    @pl.when(c * bk < kv_len)
+    def _block():
+        q = q_ref[0].astype(jnp.float32) * scale                 # (R, L)
+        k = k_ref[0].astype(jnp.float32)                         # (bk, L)
+        v = v_ref[0].astype(jnp.float32)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                precision=jax.lax.Precision.HIGHEST,
+                                preferred_element_type=jnp.float32)
+        kpos = c * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(kpos < kv_len, s, NEG_INF)
-        return _online_update(carry, s, v)
+        m = m_sc[...]
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m - m_new)
+        l_sc[...] = l_sc[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_sc[...] = acc_sc[...] * corr + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+        m_sc[...] = m_new
 
-    m0 = jnp.full((g, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((g, 1), jnp.float32)
-    a0 = jnp.zeros((g, hd), jnp.float32)
-    _finish(o_ref, jax.lax.fori_loop(0, nk, body, (m0, l0, a0)))
-
-
-def _paged_decode_kernel(q_ref, kp_ref, vp_ref, bt_ref, kvl_ref, o_ref, *,
-                         pg: int, ncols: int, scale: float):
-    """Paged pools. q_ref (1, 1, g, hd); kp/vp_ref whole (rows, pg, kvh, hd);
-    bt_ref whole (b, ncols) int32; kvl_ref whole (b,) int32.  Each streamed
-    block is one page, resolved through the slot's block-table row."""
-    g, hd = q_ref.shape[2], q_ref.shape[3]
-    slot = pl.program_id(0)
-    head = pl.program_id(1)
-    q = q_ref[0, 0].astype(jnp.float32) * scale          # (g, hd)
-    kv_len = kvl_ref[slot]
-
-    def body(c, carry):
-        row = bt_ref[slot, c]                            # int32 pool row
-        k = pl.load(kp_ref, (pl.dslice(row, 1), slice(None), head,
-                             slice(None))).reshape(pg, hd).astype(jnp.float32)
-        v = pl.load(vp_ref, (pl.dslice(row, 1), slice(None), head,
-                             slice(None))).reshape(pg, hd).astype(jnp.float32)
-        s = q @ k.T                                      # (g, pg)
-        kpos = c * pg + jax.lax.broadcasted_iota(jnp.int32, (1, pg), 1)
-        s = jnp.where(kpos < kv_len, s, NEG_INF)
-        return _online_update(carry, s, v)
-
-    m0 = jnp.full((g, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((g, 1), jnp.float32)
-    a0 = jnp.zeros((g, hd), jnp.float32)
-    _finish(o_ref, jax.lax.fori_loop(0, ncols, body, (m0, l0, a0)))
+    @pl.when(c == pl.num_programs(1) - 1)
+    def _finish():
+        o_ref[0] = (acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)
+                    ).astype(o_ref.dtype)
 
 
 def _norm_kv_len(kv_len, b: int):
     """Scalar (lockstep / cross-attn) or (b,) per-slot lengths -> (b,) i32."""
     kvl = jnp.reshape(jnp.asarray(kv_len, jnp.int32), (-1,))
     return jnp.broadcast_to(kvl, (b,))
+
+
+def _diag_queries(q, kvh: int):
+    """q (b, 1, h, hd) -> (b, R, kvh*hd): row (head, j) holds q[head, j] in
+    head's lanes, zero elsewhere; R pads kvh*g up to a sublane multiple."""
+    b, _, h, hd = q.shape
+    g = h // kvh
+    qh = q.reshape(b, kvh, g, 1, hd)
+    eye = jnp.eye(kvh, dtype=q.dtype).reshape(1, kvh, 1, kvh, 1)
+    qd = (qh * eye).reshape(b, h, kvh * hd)
+    return jnp.pad(qd, ((0, 0), (0, (-h) % 8), (0, 0)))
+
+
+def _take_diag(o, q_shape, kvh: int):
+    """Inverse of `_diag_queries` on the kernel output -> (b, 1, h, hd)."""
+    b, _, h, hd = q_shape
+    g = h // kvh
+    o = o[:, :h].reshape(b, kvh, g, kvh, hd)
+    o = jnp.einsum("bkgkd->bkgd", o)
+    return o.reshape(b, 1, h, hd)
+
+
+def _call(q, k, v, bt, kvl, *, bk: int, nblk: int, kv_block, interpret):
+    """Shared pallas_call: k/v (rows, bk, L) blocks picked by ``kv_block``
+    (slot, c, bt_ref, kvl_ref) -> block row; bt is scalar-prefetched."""
+    b, _, h, hd = q.shape
+    L = k.shape[-1]
+    kvh = L // hd
+    qd = _diag_queries(q, kvh)
+    R = qd.shape[1]
+
+    def kv_map(s, c, bt_ref, kvl_ref):
+        # past kv_len: repeat the last valid block (no new DMA, compute off)
+        last = jnp.maximum((kvl_ref[s] - 1) // bk, 0)
+        return (kv_block(s, jnp.minimum(c, last), bt_ref), 0, 0)
+
+    kernel = functools.partial(_decode_kernel, bk=bk, scale=hd ** -0.5)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, nblk),
+            in_specs=[pl.BlockSpec((1, R, L), lambda s, c, *_: (s, 0, 0)),
+                      pl.BlockSpec((1, bk, L), kv_map),
+                      pl.BlockSpec((1, bk, L), kv_map)],
+            out_specs=pl.BlockSpec((1, R, L), lambda s, c, *_: (s, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((R, 1), jnp.float32),
+                            pltpu.VMEM((R, 1), jnp.float32),
+                            pltpu.VMEM((R, L), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b, R, L), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=resolve_interpret(interpret),
+    )(bt, kvl, qd, k, v)
+    return _take_diag(out, q.shape, kvh)
 
 
 @functools.partial(jax.jit, static_argnames=("bk", "interpret"))
@@ -135,28 +160,18 @@ def flash_decode(q, k_cache, v_cache, kv_len, *, bk: int = DEFAULT_BK,
     per-slot valid lengths.  Returns (b, 1, h, hd), matching
     ``models.attention.decode_attention`` to float tolerance.
     """
-    interpret = _interpret_default() if interpret is None else interpret
-    b, _, h, hd = q.shape
-    _, S, kvh, _ = k_cache.shape
-    g = h // kvh
-    bk = min(bk, S)
-    if S % bk:
-        bk = math.gcd(S, bk)
-    qh = q.reshape(b, kvh, g, hd)
-    kernel = functools.partial(_decode_kernel, bk=bk, nk=S // bk,
-                               scale=hd ** -0.5)
-    out = pl.pallas_call(
-        kernel,
-        grid=(b, kvh),
-        in_specs=[pl.BlockSpec((1, 1, g, hd), lambda s, k_: (s, k_, 0, 0)),
-                  pl.BlockSpec((1, S, 1, hd), lambda s, k_: (s, 0, k_, 0)),
-                  pl.BlockSpec((1, S, 1, hd), lambda s, k_: (s, 0, k_, 0)),
-                  pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec((1, 1, g, hd), lambda s, k_: (s, k_, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, kvh, g, hd), q.dtype),
-        interpret=interpret,
-    )(qh, k_cache, v_cache, _norm_kv_len(kv_len, b))
-    return out.reshape(b, 1, h, hd)
+    b = q.shape[0]
+    _, S, kvh, hd = k_cache.shape
+    bk = math.gcd(S, min(bk, S))
+    if bk % 8:                       # TPU tiling: sublane multiple or all of S
+        bk = S
+    nblk = S // bk
+    k = k_cache.reshape(b * nblk, bk, kvh * hd)
+    v = v_cache.reshape(b * nblk, bk, kvh * hd)
+    dummy_bt = jnp.zeros((1,), jnp.int32)
+    return _call(q, k, v, dummy_bt, _norm_kv_len(kv_len, b), bk=bk,
+                 nblk=nblk, kv_block=lambda s, c, _: s * nblk + c,
+                 interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -170,24 +185,12 @@ def flash_decode_paged(q, k_pool, v_pool, bt, kv_len, *,
     Equivalent to gathering ``pool[bt].reshape(b, ncols*page, kv, hd)`` and
     calling ``decode_attention`` — to float tolerance, minus the copy.
     """
-    interpret = _interpret_default() if interpret is None else interpret
-    b, _, h, hd = q.shape
-    rows, pg, kvh, _ = k_pool.shape
+    b = q.shape[0]
+    rows, pg, kvh, hd = k_pool.shape
     ncols = bt.shape[-1]
-    g = h // kvh
-    qh = q.reshape(b, kvh, g, hd)
-    kernel = functools.partial(_paged_decode_kernel, pg=pg, ncols=ncols,
-                               scale=hd ** -0.5)
-    out = pl.pallas_call(
-        kernel,
-        grid=(b, kvh),
-        in_specs=[pl.BlockSpec((1, 1, g, hd), lambda s, k_: (s, k_, 0, 0)),
-                  pl.BlockSpec(memory_space=pl.ANY),
-                  pl.BlockSpec(memory_space=pl.ANY),
-                  pl.BlockSpec(memory_space=pl.ANY),
-                  pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec((1, 1, g, hd), lambda s, k_: (s, k_, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, kvh, g, hd), q.dtype),
-        interpret=interpret,
-    )(qh, k_pool, v_pool, bt.astype(jnp.int32), _norm_kv_len(kv_len, b))
-    return out.reshape(b, 1, h, hd)
+    k = k_pool.reshape(rows, pg, kvh * hd)
+    v = v_pool.reshape(rows, pg, kvh * hd)
+    return _call(q, k, v, bt.astype(jnp.int32).reshape(-1),
+                 _norm_kv_len(kv_len, b), bk=pg, nblk=ncols,
+                 kv_block=lambda s, c, bt_ref: bt_ref[s * ncols + c],
+                 interpret=interpret)

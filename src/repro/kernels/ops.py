@@ -1,8 +1,8 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 Handles flat-vector padding/reshaping to lane-aligned (blocks, block_size)
-tiles, dispatches interpret=True on CPU (validation) vs compiled on TPU, and
-exposes the API the compression layer consumes.
+tiles, leaves ``interpret=None`` to the platform (compiled on TPU, the
+interpreter elsewhere), and exposes the API the compression layer consumes.
 """
 from __future__ import annotations
 
@@ -12,10 +12,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import block_topk as bt
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _to_blocks(flat: jnp.ndarray, block_size: int):
@@ -37,7 +33,6 @@ def block_topk_sparsify(flat: jnp.ndarray, cr: float,
                         block_size: int = bt.DEFAULT_BLOCK,
                         interpret: bool = None):
     """Keep ~cr fraction per block; returns densified sparse vector (n,)."""
-    interpret = _interpret_default() if interpret is None else interpret
     g2d, n = _to_blocks(flat, block_size)
     k = max(1, int(cr * block_size))
     out, _ = bt.block_topk(g2d, k, interpret=interpret)
@@ -48,7 +43,6 @@ def block_topk_sparsify(flat: jnp.ndarray, cr: float,
 def block_topk_counts(flat: jnp.ndarray, cr: float,
                       block_size: int = bt.DEFAULT_BLOCK,
                       interpret: bool = None):
-    interpret = _interpret_default() if interpret is None else interpret
     g2d, n = _to_blocks(flat, block_size)
     k = max(1, int(cr * block_size))
     out, cnt = bt.block_topk(g2d, k, interpret=interpret)
@@ -65,7 +59,6 @@ def fused_sgdm_flat(p, m, g, lr, momentum: float = 0.9,
                     weight_decay: float = 0.0,
                     block_size: int = bt.DEFAULT_BLOCK, interpret: bool = None):
     """Fused momentum-SGD on flat vectors (one HBM pass)."""
-    interpret = _interpret_default() if interpret is None else interpret
     p2, n = _to_blocks(p, block_size)
     m2, _ = _to_blocks(m, block_size)
     g2, _ = _to_blocks(g, block_size)

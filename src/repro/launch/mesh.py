@@ -6,12 +6,9 @@ import jax
 
 
 def _mesh(dev_array, axes):
-    try:   # AxisType landed after 0.4.x; older Mesh has no axis_types kwarg
-        from jax.sharding import AxisType
-        return jax.sharding.Mesh(dev_array, axes,
-                                 axis_types=(AxisType.Auto,) * len(axes))
-    except ImportError:
-        return jax.sharding.Mesh(dev_array, axes)
+    return jax.sharding.Mesh(dev_array, axes,
+                             axis_types=(jax.sharding.AxisType.Auto,)
+                             * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.decode import (decode_step, init_cache, prefill_cache,
                                  prefill_cross_kv)
 from repro.models.transformer import RunCtx, init_params
@@ -43,6 +44,42 @@ def _setup(args):
     return cfg, ctx, params, k_prompt, k_audio, k_sample
 
 
+def offline_generate(params, cfg, ctx, tokens, gen: int, *, cache,
+                     pattern=None, temperature: float = 0.0, key=None):
+    """Static-batch generation: fused ``prefill_cache`` over the prompt
+    ``tokens`` (b, s), then ``gen`` lockstep ``decode_step`` calls.
+
+    ``cache`` is a fresh ``init_cache`` of at least ``s + gen`` entries.
+    Returns host arrays ``tokens`` (b, gen) and ``top2_gap`` (b, gen), the
+    gap between the two largest logits each token was picked from, plus
+    the ``prefill_s`` and ``decode_s`` wall times.
+    """
+    step_jit = jax.jit(
+        lambda p, c, t: decode_step(p, c, t, cfg, ctx, pattern=pattern))
+    prefill_jit = jax.jit(
+        lambda p, c, t: prefill_cache(p, t, c, cfg, ctx, pattern=pattern))
+
+    t0 = time.time()
+    logits, cache = jax.block_until_ready(prefill_jit(params, cache, tokens))
+    prefill_s = time.time() - t0
+
+    out, gaps = [], []
+    t0 = time.time()
+    for i in range(gen):
+        if temperature > 0:
+            key, sk = jax.random.split(key)
+            nxt = jax.random.categorical(sk, logits / temperature, axis=-1)
+        else:
+            nxt = jnp.argmax(logits, axis=-1)
+        top2 = jax.lax.top_k(logits, 2)[0]
+        out.append(np.asarray(nxt))
+        gaps.append(np.asarray(top2[:, 0] - top2[:, 1]))
+        if i + 1 < gen:
+            logits, cache = step_jit(params, cache, nxt[:, None])
+    return {"tokens": np.stack(out, 1), "top2_gap": np.stack(gaps, 1),
+            "prefill_s": prefill_s, "decode_s": time.time() - t0}
+
+
 def run_offline(args):
     cfg, ctx, params, k_prompt, k_audio, k_sample = _setup(args)
     pattern = cfg.pattern_for_long_context() if args.long_context else None
@@ -56,32 +93,15 @@ def run_offline(args):
 
     toks = jax.random.randint(k_prompt, (args.batch, args.prompt_len), 0,
                               cfg.vocab_size)
-    step_jit = jax.jit(
-        lambda p, c, t: decode_step(p, c, t, cfg, ctx, pattern=pattern))
-    prefill_jit = jax.jit(
-        lambda p, c, t: prefill_cache(p, t, c, cfg, ctx, pattern=pattern))
-
-    t0 = time.time()
-    logits, cache = jax.block_until_ready(prefill_jit(params, cache, toks))
-    t_prefill = time.time() - t0
-
-    out = []
-    key_s = k_sample
-    t0 = time.time()
-    for _ in range(args.gen):
-        key_s, sk = jax.random.split(key_s)
-        if args.temperature > 0:
-            nxt = jax.random.categorical(sk, logits / args.temperature,
-                                         axis=-1)
-        else:
-            nxt = jnp.argmax(logits, axis=-1)
-        out.append(np.asarray(nxt))
-        logits, cache = step_jit(params, cache, nxt[:, None])
-    dt = time.time() - t0
+    out = offline_generate(params, cfg, ctx, toks, args.gen, cache=cache,
+                           pattern=pattern, temperature=args.temperature,
+                           key=k_sample)
+    dt = out["decode_s"]
     toks_s = args.batch * args.gen / dt
-    print(f"arch={cfg.name} batch={args.batch} prefill={t_prefill:.2f}s "
+    print(f"arch={cfg.name} batch={args.batch} "
+          f"prefill={out['prefill_s']:.2f}s "
           f"decode={dt:.2f}s ({toks_s:.1f} tok/s) cache_len={cache_len}")
-    print("sample:", np.stack(out, 1)[0][:16])
+    print("sample:", out["tokens"][0][:16])
 
 
 def run_streaming(args):
@@ -157,6 +177,7 @@ def main():
                          "+ scorecard) to this path, stamped with git SHA "
                          "and seed")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.streaming:
         run_streaming(args)
     else:

@@ -4,29 +4,38 @@
         --steps 50 --batch 16 --seq 128 [--scadles] [--dist S1]
 
 Uses the same config/model/sharding stack as the dry-run, but actually
-allocates and steps on whatever jax.devices() offers (CPU here, a pod in
-production).  With ``--scadles`` the ScaDLES mechanisms are active: per-device
-streaming rates drive sample weights (Eqn 4) and the linear LR scaling rule.
+allocates and steps on whatever jax.devices() offers.  With ``--scadles``
+the ScaDLES mechanisms are active: per-device streaming rates drive sample
+weights (Eqn 4) and the linear LR scaling rule.  ``run`` is the whole
+launch minus argument parsing; ``chip_smoke.py`` calls it directly.
 """
 from __future__ import annotations
 
 import argparse
 import time
+from typing import Any, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
-from repro.core import TABLE_I, StreamSimulator, linear_scaled_lr
+from repro.core import TABLE_I, StreamSimulator
 from repro.data import TokenData
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.transformer import RunCtx, init_params
 from repro.optim import make_optimizer, warmup_cosine
 from repro.train import make_train_step
 from repro.checkpoint import save_pytree
 
 
-def main():
+def train_ctx(seq: int) -> RunCtx:
+    """The execution context every training entry point uses at ``seq``."""
+    return RunCtx(remat=True, loss_chunk=min(128, seq),
+                  chunk_q=min(128, seq), chunk_k=min(128, seq))
+
+
+def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b")
     ap.add_argument("--reduced", action="store_true")
@@ -39,22 +48,36 @@ def main():
     ap.add_argument("--n-virtual-devices", type=int, default=8)
     ap.add_argument("--ckpt", default="")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+@jax.jit
+def _global_norm(tree):
+    return jnp.sqrt(sum(jnp.sum(jnp.square(x.astype(jnp.float32)))
+                        for x in jax.tree.leaves(tree)))
+
+
+def run(args: argparse.Namespace) -> Dict[str, Any]:
+    """Train for ``args.steps`` steps; returns the config name ``arch``,
+    the final ``params``, the per-step ``history`` (host floats: loss,
+    grad_norm, lr, ...), the step's ``compile_s`` and ``run_s``, and the
+    global parameter norm before (``param_norm0``) and after
+    (``param_norm``)."""
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    ctx = RunCtx(remat=True, loss_chunk=min(128, args.seq),
-                 chunk_q=min(128, args.seq), chunk_k=min(128, args.seq))
-    key = jax.random.PRNGKey(args.seed)
-    params = init_params(key, cfg)
+    ctx = train_ctx(args.seq)
+    params = init_params(jax.random.PRNGKey(args.seed), cfg)
     n_params = sum(x.size for x in jax.tree.leaves(params))
-    print(f"arch={cfg.name} params={n_params/1e6:.1f}M devices={jax.device_count()}")
+    print(f"arch={cfg.name} params={n_params/1e6:.1f}M "
+          f"devices={jax.device_count()}")
 
     opt_init, opt_update = make_optimizer("adam", weight_decay=0.01)
     opt_state = opt_init(params)
     schedule = warmup_cosine(args.lr, max(args.steps // 10, 1), args.steps)
-    step_fn = jax.jit(make_train_step(cfg, ctx, opt_update, schedule))
+    # params and optimizer state are updated in place
+    step_fn = jax.jit(make_train_step(cfg, ctx, opt_update, schedule),
+                      donate_argnums=(0, 1))
 
     data = TokenData(vocab_size=cfg.vocab_size, seq_len=args.seq,
                      seed=args.seed)
@@ -62,8 +85,7 @@ def main():
     sim = StreamSimulator(TABLE_I[args.dist], args.n_virtual_devices,
                           seed=args.seed) if args.scadles else None
 
-    t0 = time.time()
-    for step in range(args.steps):
+    def batch_at(step: int):
         toks, labels = data.sample(rng, args.batch)
         batch = {"tokens": jnp.asarray(toks), "labels": jnp.asarray(labels)}
         if sim is not None:
@@ -73,15 +95,42 @@ def main():
             w = rates[dev].astype(np.float64)
             batch["sample_weights"] = jnp.asarray(
                 (w / w.sum()).astype(np.float32))
-        params, opt_state, metrics = step_fn(params, opt_state, batch,
-                                             jnp.asarray(step))
+        return batch
+
+    param_norm0 = float(_global_norm(params))
+    batch = batch_at(0)
+    t0 = time.perf_counter()
+    compiled = step_fn.lower(params, opt_state, batch,
+                             jnp.asarray(0)).compile()
+    compile_s = time.perf_counter() - t0
+
+    history: List[Dict[str, float]] = []
+    t0 = time.perf_counter()
+    for step in range(args.steps):
+        if step:
+            batch = batch_at(step)
+        params, opt_state, metrics = compiled(params, opt_state, batch,
+                                              jnp.asarray(step))
+        history.append({k: float(v) for k, v in metrics.items()})
         if step % 10 == 0 or step == args.steps - 1:
-            print(f"step {step:4d} loss={float(metrics['loss']):.4f} "
-                  f"lr={float(metrics['lr']):.2e} "
-                  f"gnorm={float(metrics['grad_norm']):.3f} "
-                  f"({(time.time()-t0)/(step+1):.2f}s/it)")
+            m = history[-1]
+            print(f"step {step:4d} loss={m['loss']:.4f} lr={m['lr']:.2e} "
+                  f"gnorm={m['grad_norm']:.3f} "
+                  f"({(time.perf_counter()-t0)/(step+1):.2f}s/it)")
+    run_s = time.perf_counter() - t0
+    return {"arch": cfg.name, "params": params, "history": history,
+            "compile_s": compile_s, "run_s": run_s,
+            "param_norm0": param_norm0,
+            "param_norm": float(_global_norm(params))}
+
+
+def main(argv: Optional[Sequence[str]] = None):
+    args = parse_args(argv)
+    enable_compile_cache()
+    out = run(args)
     if args.ckpt:
-        path = save_pytree({"params": params}, args.ckpt, name=cfg.name)
+        path = save_pytree({"params": out["params"]}, args.ckpt,
+                           name=out["arch"])
         print("saved", path)
 
 

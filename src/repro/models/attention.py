@@ -243,10 +243,6 @@ _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 # public entry points
 
 
-def _kernel_interpret(interpret: Optional[bool]) -> bool:
-    return jax.default_backend() != "tpu" if interpret is None else interpret
-
-
 def chunked_attention(q, k, v, *, kind: str = "causal", window: int = 0,
                       q_offset=0, chunk_q: int = 512, chunk_k: int = 512,
                       static_offset: bool = True, backend: str = "jax",
@@ -271,7 +267,7 @@ def chunked_attention(q, k, v, *, kind: str = "causal", window: int = 0,
         return flash_attention(
             q, k, v, kind=kind, window=window, q_offset=int(q_offset),
             bq=math.gcd(sq, 128), bk=math.gcd(sk, 128),
-            interpret=_kernel_interpret(interpret))
+            interpret=interpret)
     qg = q.reshape(b, sq, kvh, g, hd)
     # snap chunks to divisors of the sequence lengths (e.g. whisper's 1536
     # frames with a 1024 default -> gcd 512)
@@ -329,7 +325,7 @@ def decode_attention(q, k_cache, v_cache, kv_len, *, kind: str = "causal",
     if backend == "pallas":
         from repro.kernels.flash_decode import flash_decode
         return flash_decode(q, k_cache, v_cache, kv_len,
-                            interpret=_kernel_interpret(interpret))
+                            interpret=interpret)
     b, _, h, hd = q.shape
     _, S, kvh, _ = k_cache.shape
     g = h // kvh

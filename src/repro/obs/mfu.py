@@ -22,28 +22,21 @@ from typing import Optional
 DEVICE_PEAK_FLOPS = 4.37e12
 
 
-def lowered_flops(fn, *args) -> Optional[float]:
+def lowered_flops(fn, *args) -> float:
     """Flops of one call of jitted ``fn`` at ``args``, from optimized HLO.
 
     Primary source is ``repro.dist.hlo_cost.analyze_hlo`` (matches XLA's
-    ``cost_analysis`` to ~1e-6 and multiplies ``while`` bodies by their trip
-    count); falls back to ``Compiled.cost_analysis()`` and then to None —
-    callers treat None as "flops unavailable", never as an error.
+    ``cost_analysis`` and multiplies ``while`` bodies by their trip count);
+    where the walker counts nothing, ``Compiled.cost_analysis()`` answers.
+    Lowering or compile errors propagate: a step that cannot be compiled is
+    a fault, not a missing metric.
     """
-    try:
-        compiled = fn.lower(*args).compile()
-    except Exception:
-        return None
-    try:
-        from repro.dist.hlo_cost import analyze_hlo
-        return float(analyze_hlo(compiled.as_text())["flops"])
-    except Exception:
-        pass
-    try:
-        flops = compiled.cost_analysis().get("flops", 0.0)
-        return float(flops) if flops else None
-    except Exception:
-        return None
+    from repro.dist.hlo_cost import analyze_hlo
+    compiled = fn.lower(*args).compile()
+    flops = float(analyze_hlo(compiled.as_text())["flops"])
+    if flops == 0.0:
+        flops = float(compiled.cost_analysis().get("flops", 0.0))
+    return flops
 
 
 def mfu(step_flops: Optional[float], dt_s: float, *,

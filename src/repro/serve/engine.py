@@ -70,11 +70,8 @@ def resolve_decode_backend(ctx) -> str:
         return env
     if ctx.decode_backend != "jax":
         return ctx.decode_backend       # explicit opt-in/out in the config
-    interp = ctx.kernel_interpret
-    if interp is None:
-        from repro.kernels.flash_decode import _interpret_default
-        interp = _interpret_default()
-    return "pallas" if interp else "jax"
+    from repro.kernels import resolve_interpret
+    return "pallas" if resolve_interpret(ctx.kernel_interpret) else "jax"
 
 
 @dataclasses.dataclass(frozen=True)

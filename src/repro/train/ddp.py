@@ -18,37 +18,24 @@ from __future__ import annotations
 
 from typing import Callable, Dict, Tuple
 
-import repro.compat  # noqa: F401  (jax.shard_map / set_mesh on jax 0.4.x)
-
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.core import compression as comp_lib
-from repro.kernels.scatter_agg import scatter_aggregate
 from repro.models.transformer import RunCtx
 from repro.train.step import make_loss_fn
 
 
 def make_ddp_steps(cfg: ModelConfig, ctx: RunCtx, mesh, opt_update: Callable,
                    lr_schedule: Callable, cr: float,
-                   param_template, use_scatter_agg: bool = None,
-                   kernel_interpret: bool = None
-                   ) -> Tuple[Callable, Callable, int, int]:
+                   param_template) -> Tuple[Callable, Callable, int, int]:
     """Returns (dense_step, compressed_step, k, n_floats): the two jitted
     programs share the signature (params, opt_state, batch, rates, step) with
     params replicated and batch sharded over the mesh's data axes; ``k`` is
     the per-device top-k kept by the compressed program and ``n_floats`` the
-    flattened gradient length.
-
-    ``use_scatter_agg`` routes the compressed program's densify→scatter-add
-    tail through the fused Pallas kernel (``kernels/scatter_agg.py``,
-    bit-exact — tests/test_kernels_decode.py).  None = auto: on for compiled
-    TPU runs, off on CPU where the interpreted kernel would serialise the
-    scatter."""
-    if use_scatter_agg is None:
-        use_scatter_agg = jax.default_backend() == "tpu"
+    flattened gradient length."""
     dp = tuple(mesh.axis_names)
     loss_fn = make_loss_fn(cfg, ctx)
     flat0, unflatten = comp_lib.flatten_grads(
@@ -102,14 +89,8 @@ def make_ddp_steps(cfg: ModelConfig, ctx: RunCtx, mesh, opt_update: Callable,
         for ax in dp:
             vals = jax.lax.all_gather(vals, ax, axis=0, tiled=False)
             idx = jax.lax.all_gather(idx, ax, axis=0, tiled=False)
-        if use_scatter_agg:
-            # fused gather–scatter-add: one pass over the (D, k) packets,
-            # sequential in device order — bit-exact with the chain below
-            g = scatter_aggregate(vals.reshape(-1, k), idx.reshape(-1, k),
-                                  n_floats, interpret=kernel_interpret)
-        else:
-            g = (jnp.zeros((n_floats,), flat.dtype)
-                 .at[idx.reshape(-1)].add(vals.reshape(-1)))
+        g = (jnp.zeros((n_floats,), flat.dtype)
+             .at[idx.reshape(-1)].add(vals.reshape(-1)))
         loss = m["loss"] * w
         gap_m = gap
         for ax in dp:

@@ -1,16 +1,12 @@
-"""Pallas hot-path kernels: flash-decode over slot/ring/paged caches,
-the fused compressed-aggregation scatter, and the block_topk VJP.
+"""Pallas hot-path kernels: flash-decode over slot/ring/paged caches and
+the block_topk VJP.
 
 Oracle discipline (DESIGN.md §15): every kernel is validated in interpret
 mode against the pure-JAX path it replaces — float tolerance for the
-attention kernels (fp32 online softmax vs fp32 full softmax), bit-exact
-for ``scatter_aggregate`` (same adds, same order).
+attention kernels (fp32 online softmax vs fp32 full softmax).
 """
 import dataclasses
 import functools
-import os
-import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -24,7 +20,6 @@ from repro.kernels.flash_decode import (flash_decode,  # noqa: E402
                                         flash_decode_paged)
 from repro.kernels.ops import block_topk_counts  # noqa: E402
 from repro.kernels.ref import block_topk_ref  # noqa: E402
-from repro.kernels.scatter_agg import scatter_aggregate  # noqa: E402
 from repro.models import RunCtx, init_params  # noqa: E402
 from repro.models.attention import (chunked_attention,  # noqa: E402
                                     decode_attention)
@@ -253,94 +248,6 @@ def test_prefill_backend_matches_jax(arch):
         outs[name] = (np.asarray(logits), np.asarray(cache["pos"]))
     np.testing.assert_allclose(outs["jax"][0], outs["pallas"][0], atol=1e-4)
     np.testing.assert_array_equal(outs["jax"][1], outs["pallas"][1])
-
-
-# ---------------------------------------------------------------------------
-# scatter_aggregate: bit-exact with the densify→scatter-add chain
-
-
-def _agg_ref(vals, idx, n):
-    return (jnp.zeros((n,), vals.dtype)
-            .at[idx.reshape(-1)].add(vals.reshape(-1)))
-
-
-def test_scatter_agg_bit_exact_with_duplicates():
-    """Unique in-row indices, adversarial cross-device duplicates (up to
-    4-way): every output bit matches the reference scatter-add."""
-    rng = np.random.default_rng(1)
-    D, k, n = 4, 32, 1000
-    idx = np.stack([rng.permutation(n)[:k] for _ in range(D)])
-    idx[1, :8] = idx[0, :8]
-    idx[2, :4] = idx[0, :4]
-    idx[3, :4] = idx[0, :4]
-    vals = (rng.normal(size=(D, k)) * 1e3).astype(np.float32)
-    vals_j = jnp.asarray(vals)
-    idx_j = jnp.asarray(idx, jnp.int32)
-    ref = _agg_ref(vals_j, idx_j, n)
-    out = scatter_aggregate(vals_j, idx_j, n, interpret=True)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
-
-
-def test_scatter_agg_single_device():
-    rng = np.random.default_rng(2)
-    k, n = 16, 200
-    idx = jnp.asarray(rng.permutation(n)[:k].reshape(1, k), jnp.int32)
-    vals = jnp.asarray(rng.normal(size=(1, k)), jnp.float32)
-    out = scatter_aggregate(vals, idx, n, interpret=True)
-    np.testing.assert_array_equal(np.asarray(out),
-                                  np.asarray(_agg_ref(vals, idx, n)))
-
-
-_SHARD_MAP_SCRIPT = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-import json
-import jax, jax.numpy as jnp
-import numpy as np
-from jax.sharding import PartitionSpec as P
-import repro.compat  # noqa: F401
-from repro.kernels.scatter_agg import scatter_aggregate
-
-mesh = jax.make_mesh((4,), ("data",))
-n, k = 512, 8
-rng = np.random.default_rng(0)
-vals = jnp.asarray(rng.normal(size=(4, k)), jnp.float32)
-idx = jnp.asarray(np.stack([rng.permutation(n)[:k] for _ in range(4)]),
-                  jnp.int32)
-idx = idx.at[2, :3].set(idx[0, :3])   # cross-device duplicates
-
-def body(v_l, i_l):
-    v_all = jax.lax.all_gather(v_l, "data", axis=0, tiled=False)
-    i_all = jax.lax.all_gather(i_l, "data", axis=0, tiled=False)
-    ref = (jnp.zeros((n,), v_all.dtype)
-           .at[i_all.reshape(-1)].add(v_all.reshape(-1)))
-    fused = scatter_aggregate(v_all.reshape(-1, k), i_all.reshape(-1, k), n,
-                              interpret=True)
-    return ref, fused
-
-fn = jax.shard_map(body, mesh=mesh, in_specs=(P("data"), P("data")),
-                   out_specs=(P(), P()), check_vma=False)
-ref, fused = fn(vals, idx)
-print(json.dumps({"exact": bool(jnp.all(ref == fused))}))
-"""
-
-
-def test_scatter_agg_under_shard_map(tmp_path):
-    """The kernel inside a shard_map program over 4 fake host devices stays
-    bit-exact with the reference chain on the all-gathered packets."""
-    script = tmp_path / "scatter_shard.py"
-    script.write_text(_SHARD_MAP_SCRIPT)
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.abspath("src")
-    # force CPU: an unset JAX_PLATFORMS probes the TPU plugin (slow metadata
-    # retries on non-TPU hosts); fake host devices only need the CPU backend
-    env["JAX_PLATFORMS"] = "cpu"
-    r = subprocess.run([sys.executable, str(script)], capture_output=True,
-                       text=True, timeout=300, env=env,
-                       cwd=os.path.dirname(os.path.dirname(__file__)))
-    assert r.returncode == 0, r.stderr[-3000:]
-    import json
-    assert json.loads(r.stdout.strip().splitlines()[-1])["exact"]
 
 
 # ---------------------------------------------------------------------------
