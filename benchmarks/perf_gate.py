@@ -45,7 +45,7 @@ import time
 import numpy as np
 
 from repro.obs import (FLEET_ROUND, TRAIN_ROUND, MemoryTracker, MetricSpec,
-                       capture, capture_step, compare, load_baseline,
+                       capture, compare, load_baseline,
                        save_baseline, write_report)
 
 GATE_SEED = 0
@@ -184,11 +184,10 @@ def collect_training(profile_dir=None):
 
     if profile_dir:
         # profiler window around the jitted train step: a short tracked
-        # continuation run, traced (skipped cleanly when the profiler is
-        # unavailable on this install)
-        with capture(f"{profile_dir}/train_step") as rec:
+        # continuation run, traced
+        with capture(f"{profile_dir}/train_step"):
             out["trainer"].run(2)
-        print(f"# profile train_step: {'captured' if rec else 'skipped'}")
+        print("# profile train_step: captured")
 
     return {
         "fleet_t_target_s": out["time_to_target"],
@@ -362,9 +361,10 @@ def collect_prefill(profile_dir=None, prompt_len=64, reps=3):
     if profile_dir:
         # slot-decode capture window: the same jitted step the serving
         # schedulers drive, traced one step at a time
-        got = capture_step(lambda: step(params, mk(), toks[:, :1]), (),
-                           f"{profile_dir}/slot_decode")
-        print(f"# profile slot_decode: {'captured' if got else 'skipped'}")
+        jax.block_until_ready(step(params, mk(), toks[:, :1]))  # compile
+        with capture(f"{profile_dir}/slot_decode"):
+            jax.block_until_ready(step(params, mk(), toks[:, :1]))
+        print("# profile slot_decode: captured")
 
     return {
         "prefill_speedup_x": t_loop / t_fused,
@@ -459,8 +459,8 @@ def main(argv=None):
                     help="rewrite the baseline from fresh metrics and exit 0")
     ap.add_argument("--profile", action="store_true",
                     help="capture JAX profiler traces of the train step and "
-                         f"slot decode under {PROFILE_DIR}/ (skipped when "
-                         "the profiler is unavailable)")
+                         f"slot decode under {PROFILE_DIR}/ (fails when "
+                         "the profiler cannot start)")
     args = ap.parse_args(argv)
 
     metrics = collect(PROFILE_DIR if args.profile else None)

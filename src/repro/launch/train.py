@@ -1,17 +1,25 @@
 """Training launcher: real (small-scale) runs on the available devices.
 
     PYTHONPATH=src python -m repro.launch.train --arch qwen2-0.5b --reduced \
-        --steps 50 --batch 16 --seq 128 [--scadles] [--dist S1]
+        --steps 50 --batch 16 --seq 128 [--scadles] [--dist S1] \
+        [--trace-dir DIR]
 
 Uses the same config/model/sharding stack as the dry-run, but actually
 allocates and steps on whatever jax.devices() offers.  With ``--scadles``
 the ScaDLES mechanisms are active: per-device streaming rates drive sample
 weights (Eqn 4) and the linear LR scaling rule.  ``run`` is the whole
 launch minus argument parsing; ``chip_smoke.py`` calls it directly.
+
+Each step runs under ``jax.profiler.StepTraceAnnotation("train")`` with the
+host spans the benchmark names (``bench/harness.SPANS``): ``input`` (the
+batch), ``dispatch`` (the step's launch) and ``metrics_read`` (the one
+device-to-host read of its metrics).  ``--trace-dir`` captures steps 1 to
+N-1 in a profiler trace, beside the device's ops.
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
 import time
 from typing import Any, Dict, List, Optional, Sequence
 
@@ -24,6 +32,7 @@ from repro.core import TABLE_I, StreamSimulator
 from repro.data import TokenData
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models.transformer import RunCtx, init_params
+from repro.obs.profile import capture
 from repro.optim import make_optimizer, warmup_cosine
 from repro.train import make_train_step
 from repro.checkpoint import save_pytree
@@ -48,6 +57,8 @@ def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     ap.add_argument("--n-virtual-devices", type=int, default=8)
     ap.add_argument("--ckpt", default="")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--trace-dir", default="",
+                    help="write a profiler trace of steps 1..N-1 here")
     return ap.parse_args(argv)
 
 
@@ -105,19 +116,28 @@ def run(args: argparse.Namespace) -> Dict[str, Any]:
     compile_s = time.perf_counter() - t0
 
     history: List[Dict[str, float]] = []
+    span = jax.profiler.TraceAnnotation
     t0 = time.perf_counter()
-    for step in range(args.steps):
-        if step:
-            batch = batch_at(step)
-        params, opt_state, metrics = compiled(params, opt_state, batch,
-                                              jnp.asarray(step))
-        history.append({k: float(v) for k, v in metrics.items()})
-        if step % 10 == 0 or step == args.steps - 1:
-            m = history[-1]
-            print(f"step {step:4d} loss={m['loss']:.4f} lr={m['lr']:.2e} "
-                  f"gnorm={m['grad_norm']:.3f} "
-                  f"({(time.perf_counter()-t0)/(step+1):.2f}s/it)")
-    run_s = time.perf_counter() - t0
+    with contextlib.ExitStack() as traced:
+        for step in range(args.steps):
+            if step == 1 and args.trace_dir:
+                traced.enter_context(capture(args.trace_dir))
+            with jax.profiler.StepTraceAnnotation("train", step_num=step):
+                with span("input"):
+                    if step:
+                        batch = batch_at(step)
+                with span("dispatch"):
+                    params, opt_state, metrics = compiled(
+                        params, opt_state, batch, jnp.asarray(step))
+                with span("metrics_read"):
+                    metrics = jax.device_get(metrics)
+            history.append({k: float(v) for k, v in metrics.items()})
+            if step % 10 == 0 or step == args.steps - 1:
+                m = history[-1]
+                print(f"step {step:4d} loss={m['loss']:.4f} "
+                      f"lr={m['lr']:.2e} gnorm={m['grad_norm']:.3f} "
+                      f"({(time.perf_counter()-t0)/(step+1):.2f}s/it)")
+        run_s = time.perf_counter() - t0
     return {"arch": cfg.name, "params": params, "history": history,
             "compile_s": compile_s, "run_s": run_s,
             "param_norm0": param_norm0,

@@ -200,12 +200,17 @@ def _flash_fwd(q, k, v, qpos_base, kind, window, q_offset, chunk_q, chunk_k):
 
 def _flash_fwd_rule(q, k, v, qpos_base, kind, window, q_offset, chunk_q,
                     chunk_k):
-    out, res = _flash_fwd(q, k, v, qpos_base, kind, window, q_offset, chunk_q,
-                          chunk_k)
-    return out, res
+    with jax.named_scope("attention"):
+        return _flash_fwd(q, k, v, qpos_base, kind, window, q_offset,
+                          chunk_q, chunk_k)
 
 
 def _flash_bwd_rule(kind, window, q_offset, chunk_q, chunk_k, res, dout):
+    with jax.named_scope("attention"):
+        return _flash_bwd(kind, window, q_offset, chunk_q, chunk_k, res, dout)
+
+
+def _flash_bwd(kind, window, q_offset, chunk_q, chunk_k, res, dout):
     q, k, v, qpos_base, out, lse = res
     b, sq, kvh, g, hd = q.shape
     sk = k.shape[1]

@@ -205,30 +205,39 @@ def block_fwd(p, x, cfg: ModelConfig, ctx: RunCtx, sig: Tuple[str, str],
     """One block, training/prefill path. x (b, s, d) -> (x, aux_loss)."""
     kind, ffn = sig
     aux = jnp.zeros((), jnp.float32)
-    h = _norm(p["norm1"], x, cfg)
+    # the named scopes label the device ops in a profile; the residual adds
+    # stay outside them
     if kind in (ATTN_FULL, ATTN_SWA, ATTN_LOCAL):
-        x = x + _attention_fwd(p["attn"], h, cfg, ctx, eff_kind, window, rope)
-    elif kind == RECURRENT:
-        # recurrent scans need the sequence local; features shard instead
-        h = ctx.constrain(h, (ctx.dp_axes, None, None))
-        x = x + rglru_lib.rglru_block(p["rglru"], h)
-    elif kind == MLSTM:
-        h = ctx.constrain(h, (ctx.dp_axes, None, None))
-        x = x + xlstm_lib.mlstm_chunked(p["mlstm"], h, cfg,
-                                        chunk=min(256, h.shape[1]))
-    elif kind == SLSTM:
-        h = ctx.constrain(h, (ctx.dp_axes, None, None))
-        x = x + xlstm_lib.slstm_block(p["slstm"], h, cfg)
+        with jax.named_scope("attention"):
+            h = _norm(p["norm1"], x, cfg)
+            y = _attention_fwd(p["attn"], h, cfg, ctx, eff_kind, window, rope)
+        x = x + y
+    else:
+        h = _norm(p["norm1"], x, cfg)
+        if kind == RECURRENT:
+            # recurrent scans need the sequence local; features shard instead
+            h = ctx.constrain(h, (ctx.dp_axes, None, None))
+            x = x + rglru_lib.rglru_block(p["rglru"], h)
+        elif kind == MLSTM:
+            h = ctx.constrain(h, (ctx.dp_axes, None, None))
+            x = x + xlstm_lib.mlstm_chunked(p["mlstm"], h, cfg,
+                                            chunk=min(256, h.shape[1]))
+        elif kind == SLSTM:
+            h = ctx.constrain(h, (ctx.dp_axes, None, None))
+            x = x + xlstm_lib.slstm_block(p["slstm"], h, cfg)
     if enc_kv is not None:
-        hc = _norm(p["norm_cross"], x, cfg)
-        x = x + _cross_attention_fwd(p["cross"], hc, enc_kv, cfg, ctx)
+        with jax.named_scope("attention"):
+            hc = _norm(p["norm_cross"], x, cfg)
+            y = _cross_attention_fwd(p["cross"], hc, enc_kv, cfg, ctx)
+        x = x + y
     if ffn != "none":
-        h2 = _norm(p["norm2"], x, cfg)
-        if ffn == "moe":
-            y, aux = moe_lib.moe_ffn(p["moe"], h2, cfg, ctx)
-            x = x + y
-        else:
-            x = x + L.mlp(p["mlp"], h2, ctx)
+        with jax.named_scope("mlp"):
+            h2 = _norm(p["norm2"], x, cfg)
+            if ffn == "moe":
+                y, aux = moe_lib.moe_ffn(p["moe"], h2, cfg, ctx)
+            else:
+                y = L.mlp(p["mlp"], h2, ctx)
+        x = x + y
     return x, aux
 
 
@@ -332,7 +341,8 @@ def forward_hidden(params, tokens, cfg: ModelConfig, ctx: RunCtx,
     pattern = tuple(pattern) if pattern is not None else cfg.pattern
     b, s = tokens.shape
 
-    x = jnp.take(params["embed"], tokens, axis=0).astype(ctx.compute_dtype)
+    with jax.named_scope("vocab"):
+        x = jnp.take(params["embed"], tokens, axis=0).astype(ctx.compute_dtype)
     x = ctx.act(x)
     if cfg.family == "hybrid":  # gemma-style embedding scale
         x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
@@ -390,8 +400,9 @@ def forward_hidden(params, tokens, cfg: ModelConfig, ctx: RunCtx,
                          if enc_kv is not None else None)
         aux_total = aux_total + a
 
-    x = ctx.act(_norm(params["final_norm"], x, cfg))
-    return x, aux_total
+    with jax.named_scope("vocab"):
+        x = _norm(params["final_norm"], x, cfg)
+    return ctx.act(x), aux_total
 
 
 def _proj_cross(bp, enc_out, cfg):
@@ -414,34 +425,37 @@ def lm_loss(params, hidden, labels, cfg: ModelConfig, ctx: RunCtx,
 
     hidden (b, s, d); labels (b, s) int32. Returns mean nll over valid tokens.
     """
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    b, s, d = hidden.shape
-    c = min(ctx.loss_chunk, s)
-    assert s % c == 0
-    nchunk = s // c
-    hs = hidden.reshape(b, nchunk, c, d).swapaxes(0, 1)
-    ls = labels.reshape(b, nchunk, c).swapaxes(0, 1)
-    if loss_mask is None:
-        loss_mask = jnp.ones((b, s), jnp.float32)
-    ms = loss_mask.reshape(b, nchunk, c).swapaxes(0, 1)
+    with jax.named_scope("vocab"):
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        b, s, d = hidden.shape
+        c = min(ctx.loss_chunk, s)
+        assert s % c == 0
+        nchunk = s // c
+        hs = hidden.reshape(b, nchunk, c, d).swapaxes(0, 1)
+        ls = labels.reshape(b, nchunk, c).swapaxes(0, 1)
+        if loss_mask is None:
+            loss_mask = jnp.ones((b, s), jnp.float32)
+        ms = loss_mask.reshape(b, nchunk, c).swapaxes(0, 1)
 
-    # checkpointed: the backward recomputes each chunk's logits instead of
-    # stashing (b, c, V) probability tensors per chunk (the flash-attention
-    # argument, applied to the LM head)
-    @jax.checkpoint
-    def chunk_nll(carry, inp):
-        h, lab, m = inp
-        logits = ctx.constrain(jnp.dot(h, head).astype(jnp.float32),
-                               (ctx.dp_axes, None, ctx.tp_axis))
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, lab[..., None], axis=-1)[..., 0]
-        nll = (lse - gold) * m
-        return carry + jnp.sum(nll), None
+        # checkpointed: the backward recomputes each chunk's logits instead
+        # of stashing (b, c, V) probability tensors per chunk (the
+        # flash-attention argument, applied to the LM head)
+        @jax.checkpoint
+        def chunk_nll(carry, inp):
+            h, lab, m = inp
+            logits = ctx.constrain(jnp.dot(h, head).astype(jnp.float32),
+                                   (ctx.dp_axes, None, ctx.tp_axis))
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            gold = jnp.take_along_axis(logits, lab[..., None],
+                                       axis=-1)[..., 0]
+            nll = (lse - gold) * m
+            return carry + jnp.sum(nll), None
 
-    total, _ = jax.lax.scan(chunk_nll, jnp.zeros((), jnp.float32), (hs, ls, ms))
-    if not normalize:
-        return total
-    return total / jnp.maximum(jnp.sum(loss_mask), 1.0)
+        total, _ = jax.lax.scan(chunk_nll, jnp.zeros((), jnp.float32),
+                                (hs, ls, ms))
+        if not normalize:
+            return total
+        return total / jnp.maximum(jnp.sum(loss_mask), 1.0)
 
 
 def logits_fn(params, hidden, cfg: ModelConfig):

@@ -9,7 +9,7 @@ The subsystem every perf claim reports through (DESIGN.md §12):
   samples/s, wire bytes), fleet commit telemetry, serve request events.
 * ``mfu``       — model-flops utilisation from the lowered step program via
   ``repro.dist.hlo_cost``'s trip-count-aware walker.
-* ``profile``   — failure-tolerant JAX profiler capture windows.
+* ``profile``   — JAX profiler capture windows.
 * ``regress``   — the perf-regression gate: tolerance-banded comparison of
   fresh metrics against the committed ``BENCH_scadles.json`` baseline
   (driven by ``benchmarks/perf_gate.py`` in CI).
@@ -24,7 +24,7 @@ from repro.obs.callbacks import (FLEET_ROUND, SERVE_EVENT,  # noqa: F401
                                  RoundObserver, fleet_round_record,
                                  ring_wire_bytes_per_device, serve_event)
 from repro.obs.mfu import DEVICE_PEAK_FLOPS, lowered_flops, mfu  # noqa: F401
-from repro.obs.profile import capture, capture_step, profiler_available  # noqa: F401
+from repro.obs.profile import capture  # noqa: F401
 from repro.obs.regress import (GateReport, MetricSpec, compare,  # noqa: F401
                                load_baseline, save_baseline, write_report)
 from repro.obs.tracker import (NOOP, SCHEMA_VERSION, CompositeTracker,  # noqa: F401

@@ -1,72 +1,23 @@
 """JAX profiler capture windows around the hot paths.
 
-Thin, failure-tolerant wrappers over ``jax.profiler.trace``: a capture that
-cannot start (profiler missing, tensorboard plugin absent, double-capture)
-degrades to a no-op instead of failing the run — profiling is observability,
-and observability must never take the workload down.
-
-* :func:`capture` — context manager; yields True iff a trace is recording.
-* :func:`capture_step` — convenience: run a jitted callable once under a
-  capture window (the shape used for the train step and the slot decode
-  step) and return the trace directory, or None when skipped.
+* :func:`capture` — context manager: a ``jax.profiler`` trace written under
+  ``logdir`` while the body runs.  A profiler that cannot start raises: a
+  run asked to be traced that ran untraced would leave a reader of its
+  trace nothing to read, and no sign why.
 """
 from __future__ import annotations
 
 import contextlib
 import os
-from typing import Callable, Iterator, Optional, Sequence
-
-
-def profiler_available() -> bool:
-    """Whether ``jax.profiler.trace`` exists on this install."""
-    try:
-        import jax.profiler
-        return hasattr(jax.profiler, "trace")
-    except Exception:
-        return False
+from typing import Iterator
 
 
 @contextlib.contextmanager
-def capture(logdir: Optional[str], enabled: bool = True) -> Iterator[bool]:
-    """Profiler capture window writing to ``logdir``.
-
-    Yields True while a trace is recording; yields False (and runs the body
-    untraced) when disabled, ``logdir`` is None, or the profiler is
-    unavailable/unstartable.  Exceptions from the body propagate; exceptions
-    from the profiler itself never do.
-    """
-    if not enabled or logdir is None or not profiler_available():
-        yield False
-        return
+def capture(logdir: str) -> Iterator[None]:
+    """Profiler capture window writing an ``.xplane.pb`` under ``logdir``
+    (created if missing).  Exceptions from the profiler and from the body
+    propagate."""
     import jax.profiler
-    try:
-        os.makedirs(logdir, exist_ok=True)
-        cm = jax.profiler.trace(logdir)
-        cm.__enter__()
-    except Exception:
-        yield False
-        return
-    try:
-        yield True
-    finally:
-        try:
-            cm.__exit__(None, None, None)
-        except Exception:
-            pass
-
-
-def capture_step(fn: Callable, args: Sequence, logdir: str,
-                 reps: int = 1) -> Optional[str]:
-    """Run ``fn(*args)`` ``reps`` times inside a capture window.
-
-    Blocks on the result so the trace contains the actual device work, not
-    just dispatch.  Returns ``logdir`` when a trace was recorded, None when
-    capture was skipped.
-    """
-    import jax
-    jax.block_until_ready(fn(*args))        # compile outside the window
-    with capture(logdir) as recording:
-        for _ in range(max(int(reps), 1)):
-            out = fn(*args)
-        jax.block_until_ready(out)
-    return logdir if recording else None
+    os.makedirs(logdir, exist_ok=True)
+    with jax.profiler.trace(logdir):
+        yield

@@ -59,8 +59,9 @@ def make_ddp_steps(cfg: ModelConfig, ctx: RunCtx, mesh, opt_update: Callable,
 
     def _update(params, opt_state, g_flat, step, metrics):
         grads = unflatten(g_flat)
-        lr = lr_schedule(step)
-        params, opt_state = opt_update(grads, opt_state, params, lr)
+        with jax.named_scope("optimizer"):
+            lr = lr_schedule(step)
+            params, opt_state = opt_update(grads, opt_state, params, lr)
         return params, opt_state, metrics
 
     # ---------------- dense program ----------------
@@ -82,15 +83,19 @@ def make_ddp_steps(cfg: ModelConfig, ctx: RunCtx, mesh, opt_update: Callable,
         grads, m = local_loss_and_grads(params, batch)
         w, _ = _weights(rate[0])
         flat, _ = comp_lib.flatten_grads(grads)
-        vals, idx = comp_lib.global_topk(flat, k)
-        gap = comp_lib.energy_gap(flat, comp_lib.densify(vals, idx, n_floats))
+        with jax.named_scope("topk"):
+            vals, idx = comp_lib.global_topk(flat, k)
+        with jax.named_scope("scatter_add"):
+            dense = comp_lib.densify(vals, idx, n_floats)
+        gap = comp_lib.energy_gap(flat, dense)
         # pack (r_i * values, indices) and all-gather across devices
         vals = vals * w
         for ax in dp:
             vals = jax.lax.all_gather(vals, ax, axis=0, tiled=False)
             idx = jax.lax.all_gather(idx, ax, axis=0, tiled=False)
-        g = (jnp.zeros((n_floats,), flat.dtype)
-             .at[idx.reshape(-1)].add(vals.reshape(-1)))
+        with jax.named_scope("scatter_add"):
+            g = (jnp.zeros((n_floats,), flat.dtype)
+                 .at[idx.reshape(-1)].add(vals.reshape(-1)))
         loss = m["loss"] * w
         gap_m = gap
         for ax in dp:

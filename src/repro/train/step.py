@@ -72,11 +72,12 @@ def make_train_step(cfg: ModelConfig, ctx: RunCtx, opt_update: Callable,
                                      has_aux=True)
 
     def finish(params, opt_state, grads, total, metrics, step):
-        lr = lr_schedule(step)
-        params, opt_state = opt_update(grads, opt_state, params, lr)
-        gnorm = jnp.sqrt(sum(
-            jnp.sum(jnp.square(g.astype(jnp.float32)))
-            for g in jax.tree.leaves(grads)))
+        with jax.named_scope("optimizer"):
+            lr = lr_schedule(step)
+            params, opt_state = opt_update(grads, opt_state, params, lr)
+            gnorm = jnp.sqrt(sum(
+                jnp.sum(jnp.square(g.astype(jnp.float32)))
+                for g in jax.tree.leaves(grads)))
         metrics = dict(metrics, total=total, grad_norm=gnorm, lr=lr)
         return params, opt_state, metrics
 
