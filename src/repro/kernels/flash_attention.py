@@ -6,8 +6,9 @@ this kernel keeps the (bq x bk) score tile in VMEM so HBM sees only q/k/v/out.
 Grid: (batch*q_heads, sq/bq); each program streams KV blocks with a fori_loop
 carrying (m, l, acc) — the same math as ``models/attention.py``'s pure-JAX
 path, which doubles as this kernel's oracle (GQA handled by the wrapper via
-kv-head indexing).  Forward only: training uses the custom-VJP JAX path for
-the backward; serving prefill is where this kernel pays off.
+kv-head indexing).  Forward only, at HIGHEST precision, for serving
+prefill (``backend="pallas"``); training takes the forward and backward
+kernels of ``kernels/flash_train.py``.
 
 Validated in interpret mode on CPU (tests/test_kernels_flash.py) and
 compiled for v5e in tests/test_tpu_compile.py; ``interpret=None`` lets the

@@ -31,6 +31,7 @@ from repro.configs import get_config
 from repro.core import TABLE_I, StreamSimulator
 from repro.data import TokenData
 from repro.launch.compile_cache import enable_compile_cache
+from repro.models import attention
 from repro.models.transformer import RunCtx, init_params
 from repro.obs.profile import capture
 from repro.optim import make_optimizer, warmup_cosine
@@ -73,7 +74,9 @@ def run(args: argparse.Namespace) -> Dict[str, Any]:
     the final ``params``, the per-step ``history`` (host floats: loss,
     grad_norm, lr, ...), the step's ``compile_s`` and ``run_s``, and the
     global parameter norm before (``param_norm0``) and after
-    (``param_norm``)."""
+    (``param_norm``), and ``attention_paths``: the attention calls the
+    step's trace sent to the Pallas kernels and to the JAX path (logged
+    after the compile; a layer scan counts once)."""
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -110,10 +113,14 @@ def run(args: argparse.Namespace) -> Dict[str, Any]:
 
     param_norm0 = float(_global_norm(params))
     batch = batch_at(0)
+    paths0 = attention.path_counts()
     t0 = time.perf_counter()
     compiled = step_fn.lower(params, opt_state, batch,
                              jnp.asarray(0)).compile()
     compile_s = time.perf_counter() - t0
+    paths = {k: n - paths0[k] for k, n in attention.path_counts().items()}
+    print(f"attention calls traced: {paths['kernel']} on the Pallas kernels, "
+          f"{paths['jax']} on the JAX path")
 
     history: List[Dict[str, float]] = []
     span = jax.profiler.TraceAnnotation
@@ -140,6 +147,7 @@ def run(args: argparse.Namespace) -> Dict[str, Any]:
         run_s = time.perf_counter() - t0
     return {"arch": cfg.name, "params": params, "history": history,
             "compile_s": compile_s, "run_s": run_s,
+            "attention_paths": paths,
             "param_norm0": param_norm0,
             "param_norm": float(_global_norm(params))}
 

@@ -1,15 +1,22 @@
-"""Attention: flash-style chunked softmax attention in pure JAX.
+"""Attention: flash-style chunked softmax attention.
 
 Execution modes (DESIGN.md §5):
 
-* ``chunked_attention`` — local (per-shard) attention.  The query axis is
-  blocked by a static Python loop so causal/SWA layers statically skip
-  fully-masked KV blocks (sub-quadratic for SWA); each query block runs an
-  online-softmax ``lax.scan`` over its KV blocks, so ``s_q x s_k`` scores are
-  never materialised.  A **custom VJP** recomputes block scores in the
-  backward pass (saving only out + logsumexp), otherwise jax's scan autodiff
-  stashes every block's probability matrix — O(s_q*s_k) — which is exactly
-  the memory wall flash attention exists to avoid.
+* ``chunked_attention`` — local (per-shard) attention.  On the TPU a call
+  that ``kernel_route`` accepts (static offset; causal, swa or bidir;
+  lengths in multiples of 128 and long enough for the GQA ratio; head_dim a
+  multiple of 8 up to 256) runs the Pallas forward, dQ and dK/dV kernels of
+  ``kernels/flash_train.py`` under the custom VJP ``_flash_kernel``.  Every
+  other call — off the TPU, traced offsets (context parallel), odd or short
+  lengths — takes the JAX path: the query axis is blocked by a static
+  Python loop so causal/SWA layers statically skip fully-masked KV blocks
+  (sub-quadratic for SWA); each query block runs an online-softmax
+  ``lax.scan`` over its KV blocks, so ``s_q x s_k`` scores are never
+  materialised.  Both paths are **custom VJPs** that recompute
+  block scores in the backward pass (saving only out + logsumexp), otherwise
+  jax's scan autodiff stashes every block's probability matrix —
+  O(s_q*s_k) — which is exactly the memory wall flash attention exists to
+  avoid.  The JAX path's rules are the kernels' oracle.
 * ``context_parallel_attention`` — shard_map over the tensor axis for archs
   whose head count does not divide the 16-way model axis: queries stay
   sequence-sharded, K/V are all-gathered, block skipping degrades to masking
@@ -245,6 +252,80 @@ _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
 # ---------------------------------------------------------------------------
+# the same rules as Pallas kernels (TPU only; ``kernel_route`` decides)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash_kernel(q, k, v, kind: str, window: int, q_offset: int):
+    return _flash_kernel_fwd(q, k, v, kind, window, q_offset)[0]
+
+
+def _flash_kernel_fwd(q, k, v, kind, window, q_offset):
+    """q (b,sq,h,hd), k/v (b,sk,kv,hd); saves (q, k, v, o, lse)."""
+    from repro.kernels.flash_train import flash_fwd
+    with jax.named_scope("attention"):
+        o, lse = flash_fwd(q, k, v, kind=kind, window=window,
+                           q_offset=q_offset)
+    return o, (q, k, v, o, lse)
+
+
+def _flash_kernel_bwd(kind, window, q_offset, res, dout):
+    from repro.kernels.flash_train import flash_bwd
+    with jax.named_scope("attention"):
+        return flash_bwd(*res, dout, kind=kind, window=window,
+                         q_offset=q_offset)
+
+
+_flash_kernel.defvjp(_flash_kernel_fwd, _flash_kernel_bwd)
+
+# attention calls traced so far, by the path each took
+_PATH_COUNTS = {"kernel": 0, "jax": 0}
+
+
+def path_counts() -> dict:
+    """How many ``chunked_attention`` calls took the Pallas kernels and how
+    many the JAX path, counted when traced (a layer scan traces its body
+    once, however many layers it runs)."""
+    return dict(_PATH_COUNTS)
+
+
+def kernel_route(platform: str, kind: str, sq: int, sk: int, hd: int,
+                 q_offset, g: int) -> bool:
+    """Whether an attention call takes the Pallas training kernels
+    (``kernels/flash_train.py``).  They can: on the TPU, with a static
+    ``q_offset`` (None when traced) that puts each query of a causal or SWA
+    call at or after key 0 and at most at the last key, a causal, swa or
+    bidir mask, lengths in multiples of 128, and head_dim a multiple of 8
+    up to 256.  They pay: where both lengths reach ``KERNEL_MIN_LEN``
+    times ``g`` (query heads per KV head) capped at 4.  Everything else
+    takes the JAX path."""
+    if platform != "tpu" or q_offset is None:
+        return False
+    if kind not in ("causal", "swa", "bidir"):
+        return False
+    if kind != "bidir" and not 0 <= q_offset <= sk - sq:
+        return False
+    if sq % 128 or sk % 128 or hd % 8 or hd > 256:
+        return False
+    return min(sq, sk) >= KERNEL_MIN_LEN * min(g, 4)
+
+
+# Part of the JAX path's cost grows with the KV heads (its per-chunk K/V
+# slices and dK/dV updates), and past 2048 it grows faster than the square
+# of the length; a kernel grid step costs about as much at 512 as at 2048.
+# Kernel over JAX time of a layer's forward, remat forward and backward,
+# causal, head_dim 64, on a v5e (16 query heads unless noted; g7 is 14/2):
+#
+#   length   g1     g2     g4     g7     g8
+#   512      1.19   1.66   1.71   1.80   1.81
+#   1024     0.84   1.27   1.45
+#   2048     0.59   0.89   1.02   1.41   1.17
+#   4096     0.13   0.26   0.38   0.66   0.56
+#   8192                          0.56   0.47
+KERNEL_MIN_LEN = 1024
+
+
+# ---------------------------------------------------------------------------
 # public entry points
 
 
@@ -262,6 +343,8 @@ def chunked_attention(q, k, v, *, kind: str = "causal", window: int = 0,
     (``kernels/flash_attention.py``, forward-only — serving prefill).  Traced
     offsets (context parallel) always take the JAX path; ``interpret`` is
     the Pallas interpret override (None = autodetect: interpret off-TPU).
+    Otherwise the call takes the training kernels where ``kernel_route``
+    says it can, and the JAX path elsewhere.
     """
     b, sq, h, hd = q.shape
     _, sk, kvh, _ = k.shape
@@ -273,6 +356,11 @@ def chunked_attention(q, k, v, *, kind: str = "causal", window: int = 0,
             q, k, v, kind=kind, window=window, q_offset=int(q_offset),
             bq=math.gcd(sq, 128), bk=math.gcd(sk, 128),
             interpret=interpret)
+    static = q_offset if static_offset else None
+    if kernel_route(jax.default_backend(), kind, sq, sk, hd, static, g):
+        _PATH_COUNTS["kernel"] += 1
+        return _flash_kernel(q, k, v, kind, window, int(q_offset))
+    _PATH_COUNTS["jax"] += 1
     qg = q.reshape(b, sq, kvh, g, hd)
     # snap chunks to divisors of the sequence lengths (e.g. whisper's 1536
     # frames with a 1024 default -> gcd 512)

@@ -1,5 +1,6 @@
 """v5e AOT compiles of the Pallas kernels on the main path, at qwen2-0.5b
-shapes (14 query heads, 2 KV heads, head_dim 64).
+shapes (14 query heads, 2 KV heads, head_dim 64), and the training
+attention kernels at the benchmark's qwen1.5-0.5b shape as well.
 
 Nothing runs here: the TPU compiler is installed without a chip and accepts
 or refuses each kernel as the chip would — block tiling, memory spaces,
@@ -19,6 +20,7 @@ from repro.kernels.block_topk import block_topk, fused_sgdm  # noqa: E402
 from repro.kernels.flash_attention import flash_attention_fwd  # noqa: E402
 from repro.kernels.flash_decode import (flash_decode,  # noqa: E402
                                         flash_decode_paged)
+from repro.kernels.flash_train import flash_bwd, flash_fwd  # noqa: E402
 
 CFG = get_config("qwen2-0.5b")
 H, KVH, HD = CFG.num_heads, CFG.num_kv_heads, CFG.head_dim
@@ -86,6 +88,21 @@ def test_flash_attention_fwd_compiles_for_v5e(one_chip, kind):
     _compile(lambda q, k, v: flash_attention_fwd(q, k, v, kind=kind,
                                                  window=256,
                                                  interpret=False), x, x, x)
+
+
+@pytest.mark.parametrize("b,s,h,kvh", [(2, 2048, 16, 16),   # qwen1.5-0.5b
+                                       (4, 512, H, KVH)])    # qwen2-0.5b
+def test_flash_train_fwd_bwd_compile_for_v5e(one_chip, b, s, h, kvh):
+    """The training kernels (forward, dQ, dK/dV), causal at head_dim 64,
+    at the benchmark cell's shape and at qwen2-0.5b's bring-up shape."""
+    def fwd_bwd(q, k, v, do):
+        o, lse = flash_fwd(q, k, v, kind="causal", interpret=False)
+        return flash_bwd(q, k, v, o, lse, do, kind="causal", interpret=False)
+
+    qs = jax.ShapeDtypeStruct((b, s, h, HD), jnp.float32, sharding=one_chip)
+    ks = jax.ShapeDtypeStruct((b, s, kvh, HD), jnp.float32, sharding=one_chip)
+    text = _compile(fwd_bwd, qs, ks, ks, qs).as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
 
 
 def test_block_topk_compiles_for_v5e(one_chip):
