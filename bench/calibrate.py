@@ -3,7 +3,7 @@
 
     python3 bench/calibrate.py --workload <cell> --seeds 1,2,3 \\
         [--control-seeds 1,2,3] [--faults half_batch,no_exchange] \\
-        [--out calibrate.<cell>.json]
+        [--fault-seeds 1,2,3] [--out calibrate.<cell>.json]
 
 For each seed, in one process and at the cell's own size, without a
 measured window:
@@ -14,11 +14,13 @@ measured window:
 - ``control`` (``--control-seeds``): the reference computed in bfloat16,
   put in the program's place, against the float32 reference (the upper
   readings);
-- each fault of ``--faults``, planted in the program (``state_unchanged``,
+- each fault of ``--faults``, on each seed of ``--fault-seeds`` (by
+  default the control's), planted in the program (``state_unchanged``,
   ``half_batch`` and ``no_exchange`` through ``bench/program.py``) or, with
   a ``ref:`` prefix, in the reference put in the program's place.
 
-Prints one JSON line per reading and writes them all to ``--out``.
+Prints one JSON line per reading, with ``correct`` as the cell's limits
+judge it, and writes them all to ``--out``.
 """
 import time
 
@@ -32,7 +34,7 @@ from pathlib import Path  # noqa: E402
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
-from bench import harness  # noqa: E402
+from bench import compare, harness  # noqa: E402
 
 
 def reference_in_place(cell, dtype, fault="", ref=None):
@@ -42,7 +44,6 @@ def reference_in_place(cell, dtype, fault="", ref=None):
     import jax
     import jax.numpy as jnp
 
-    from bench import compare
     from bench import weights as W
     from bench.reference import train as ref_train
     from bench.traffic import Traffic
@@ -85,6 +86,7 @@ def main(argv=None):
     ap.add_argument("--seeds", default="")
     ap.add_argument("--control-seeds", default="")
     ap.add_argument("--faults", default="")
+    ap.add_argument("--fault-seeds", default=None)
     ap.add_argument("--out", default="")
     args = ap.parse_args(argv)
     root = harness.ROOT
@@ -97,10 +99,13 @@ def main(argv=None):
     seeds = [int(s) for s in args.seeds.split(",") if s]
     cseeds = [int(s) for s in args.control_seeds.split(",") if s]
     faults = [f for f in args.faults.split(",") if f]
+    fseeds = cseeds if args.fault_seeds is None else [
+        int(s) for s in args.fault_seeds.split(",") if s]
     rows = []
 
     def emit(kind, seed, nums, t0):
         row = {"kind": kind, "seed": seed, "s": time.perf_counter() - t0,
+               "correct": compare.judge(nums, spec["limits"])[0],
                **{k: v["value"] for k, v in nums.items()},
                "at": {k: v.get("at") for k, v in nums.items()}}
         rows.append(row)
@@ -110,13 +115,15 @@ def main(argv=None):
         t0 = time.perf_counter()
         nums, _ = program_readings(root, args.workload, seed, "", devices)
         emit("program", seed, nums, t0)
-    for seed in cseeds:
+    for seed in dict.fromkeys(cseeds + fseeds):
         cell = harness.Cell(root, args.workload, entry, spec, config,
                             traffic, seed, devices, harness.Spans())
-        t0 = time.perf_counter()
-        nums, ref = reference_in_place(cell, jnp.bfloat16)
-        emit("control", seed, nums, t0)
-        for f in faults:
+        ref = None
+        if seed in cseeds:
+            t0 = time.perf_counter()
+            nums, ref = reference_in_place(cell, jnp.bfloat16)
+            emit("control", seed, nums, t0)
+        for f in faults if seed in fseeds else []:
             t0 = time.perf_counter()
             if f.startswith("ref:"):
                 nums, _ = reference_in_place(cell, jnp.float32, f[4:], ref)

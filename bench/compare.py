@@ -19,7 +19,8 @@ one layer's part of a stacked weight, or a whole unstacked weight.
   exact comparison, limit 0.
 
 Each number passes when it is at most its limit; the workload file gives
-the limits, and PERF.md the readings each was set from.
+the limits, one for every number computed and none for a number that is
+not, and PERF.md the readings each was set from.
 """
 from __future__ import annotations
 
@@ -66,6 +67,9 @@ def numbers(prog: dict, ref: dict, names: List[str]) -> Dict[str, dict]:
 
 def judge(nums: Dict[str, dict], limits: Dict[str, float]):
     """-> (correct, checks): every number beside its limit."""
+    missing = sorted(set(limits) - set(nums))
+    if missing:
+        raise KeyError(f"limits for numbers never computed: {missing}")
     checks = []
     for name, rec in nums.items():
         limit = limits[name]

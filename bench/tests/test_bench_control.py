@@ -64,3 +64,13 @@ def test_reference_in_place_of_itself_is_correct(tmp_path):
     nums, _ = calibrate.reference_in_place(cell, jnp.float32)
     correct, checks = compare.judge(nums, spec["limits"])
     assert correct and all(c["value"] == 0.0 for c in checks)
+
+
+@pytest.mark.parametrize("limits", [
+    {"loss_gap": 1.0},
+    {"loss_gap": 1.0, "grad_norm_gap": 1.0, "change_median_gap": 1.0}])
+def test_judge_wants_one_limit_for_each_number(limits):
+    from bench import compare
+    nums = {"loss_gap": {"value": 0.0}, "grad_norm_gap": {"value": 0.0}}
+    with pytest.raises(KeyError):
+        compare.judge(nums, limits)

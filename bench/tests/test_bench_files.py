@@ -102,3 +102,14 @@ def test_traffic_repeats_for_a_seed_and_keeps_its_sizes():
     assert abs(float(a[0]["sample_weights"].sum()) - 1.0) < 1e-6
     with pytest.raises(ValueError):
         b.batch(5)
+
+
+def test_ddp_cells_hold_picks_and_few_cells_take_four_chips():
+    four = [w["name"] for w in BENCH["workloads"] if w["chips"] == 4]
+    assert all(w["chips"] in (1, 4) for w in BENCH["workloads"])
+    assert len(four) <= max(1, len(CELLS) // 2), four
+    for cell in CELLS:
+        spec = json.loads((ROOT / "bench" / "workloads" /
+                           f"{cell}.json").read_text())
+        if spec["driver"] == "ddp_adaptive":
+            assert spec["limits"]["picks_differ"] == 0, cell
