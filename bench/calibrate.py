@@ -48,8 +48,8 @@ def reference_in_place(cell, dtype, fault="", ref=None):
     from bench.reference import train as ref_train
     from bench.traffic import Traffic
 
-    spec, c = cell.spec, cell.config
-    init = W.make_init(c)
+    spec, c, model = cell.spec, cell.config, cell.reference
+    init = cell.arch.make_init(c)
     key = W.jax_key(cell.seed)
     fresh = Traffic(cell.traffic, c["vocab_size"], cell.seed)
     batches = [fresh.batch(i) for i in range(spec["check_steps"])]
@@ -57,12 +57,12 @@ def reference_in_place(cell, dtype, fault="", ref=None):
     with jax.default_device(cell.devices[0]):
         if spec["driver"] == "train_step":
             run = lambda dt, f: ref_train.follow_adam(  # noqa: E731
-                c, init, key, batches, spec["optimizer"], spec["schedule"],
-                dtype=dt, fault=f)
+                model, c, init, key, batches, spec["optimizer"],
+                spec["schedule"], dtype=dt, fault=f)
         else:
             run = lambda dt, f: ref_train.follow_ddp(  # noqa: E731
-                c, init, key, batches, len(cell.devices), spec["optimizer"],
-                spec["compression"], dtype=dt, fault=f)
+                model, c, init, key, batches, len(cell.devices),
+                spec["optimizer"], spec["compression"], dtype=dt, fault=f)
         ref = run(jnp.float32, "") if ref is None else ref
         other = run(dtype, fault)
     return compare.numbers(other, ref, names), ref

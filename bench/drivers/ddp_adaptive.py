@@ -43,13 +43,13 @@ class Session:
 
         self.cell, spec, c = cell, cell.spec, cell.config
         self.spans = cell.spans
-        cfg = program.model_config(c)
+        cfg = cell.arch.model_config(c)
         opt, comp = spec["optimizer"], spec["compression"]
         self.n_dev = len(cell.devices)
         self.mesh = Mesh(np.array(cell.devices), ("data",))
         rep = NamedSharding(self.mesh, P())
         self.rows = NamedSharding(self.mesh, P("data", None))
-        self.init = W.make_init(c)
+        self.init = cell.arch.make_init(c)
         template = jax.eval_shape(lambda k: init_params(k, cfg),
                                   jax.random.PRNGKey(0))
         W.check_layout(self.init, template)
@@ -156,8 +156,8 @@ class Session:
         batches = [fresh.batch(i) for i in range(spec["check_steps"])]
         with jax.default_device(cell.devices[0]):
             ref = ref_train.follow_ddp(
-                cell.config, self.init, self.key, batches, self.n_dev,
-                spec["optimizer"], spec["compression"])
+                cell.reference, cell.config, self.init, self.key, batches,
+                self.n_dev, spec["optimizer"], spec["compression"])
         return compare.numbers(self.readings, ref, self.names)
 
 

@@ -40,10 +40,10 @@ class Session:
         self.cell, spec, c = cell, cell.spec, cell.config
         self.spans = cell.spans
         self.device = cell.devices[0]
-        cfg = program.model_config(c)
+        cfg = cell.arch.model_config(c)
         seq = cell.traffic["seq_len"]
         opt, sched = spec["optimizer"], spec["schedule"]
-        self.init = W.make_init(c)
+        self.init = cell.arch.make_init(c)
         template = jax.eval_shape(lambda k: init_params(k, cfg),
                                   jax.random.PRNGKey(0))
         W.check_layout(self.init, template)
@@ -110,9 +110,9 @@ class Session:
         fresh = Traffic(cell.traffic, cell.config["vocab_size"], cell.seed)
         batches = [fresh.batch(i) for i in range(spec["check_steps"])]
         with jax.default_device(self.device):
-            ref = ref_train.follow_adam(cell.config, self.init, self.key,
-                                        batches, spec["optimizer"],
-                                        spec["schedule"])
+            ref = ref_train.follow_adam(cell.reference, cell.config,
+                                        self.init, self.key, batches,
+                                        spec["optimizer"], spec["schedule"])
         return compare.numbers(self.readings, ref, self.names)
 
 

@@ -9,14 +9,24 @@ configuration, traffic mix or metric needs new files and no edit here:
   the metrics it reports;
 - ``bench/workloads/<cell>.json``: the driver, the optimizer and schedule,
   the number of steps the reference follows, the limits of ``correct``;
-- ``bench/configs/<config>.json``: the model's published sizes;
+- ``bench/configs/<config>.json``: the model's published sizes, and its
+  ``model_type``, by which the two files of its architecture are found:
+- ``bench/arch/<model_type>.py``: what touches the program, ``model_config
+  (c)`` (the program's configuration), ``make_init(c, dtype)`` (weights
+  from the seed in the program's layout) and ``train_flops_per_token(c,
+  seq_len)``;
+- ``bench/reference/<model_type>.py``: the plain reference,
+  ``weighted_loss(params, tokens, labels, row_weights, c)``, which imports
+  nothing of the program;
 - ``bench/traffic/<traffic>.json``: parameters of ``bench/traffic.py``;
 - ``bench/drivers/<driver>.py``: ``setup(cell) -> session`` builds the
   program's step and its state, warms every shape and runs the first steps
   that the reference will follow; ``session.step()`` runs one step of the
   window; ``session.free()`` drops the program's state;
   ``session.check()`` runs the reference and returns the compared numbers;
-- ``bench/metrics/<metric>.py``: ``read(run) -> float | None``.
+- ``bench/metrics/<metric>.py``: ``read(run) -> float | None``.  A traced
+  run carries ``bench/scopes.read``'s trace, with each op's named-scope
+  path; the workload file's ``"scopes"`` names the step's scopes.
 
 A run: the look for the chip, set-up (``setup_s`` counts from the process's
 start to the first timed step), the window of ``--seconds`` (steps are
@@ -33,6 +43,7 @@ from __future__ import annotations
 import argparse
 import contextlib
 import dataclasses
+import functools
 import gc
 import importlib.util
 import json
@@ -70,6 +81,18 @@ def load_module(path: Path):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def model_module(root: Path, c: dict, part: str):
+    """``bench/<part>/<model_type>.py`` of configuration ``c``: ``part``
+    "arch" or "reference".  A ``model_type`` without its file is an error
+    that names the file."""
+    path = root / "bench" / part / f"{c['model_type']}.py"
+    if not path.is_file():
+        raise FileNotFoundError(
+            f"configuration {c['name']!r} has model_type "
+            f"{c['model_type']!r}, and there is no {path.relative_to(root)}")
+    return load_module(path)
 
 
 def enable_compile_cache(root: Path) -> str:
@@ -159,6 +182,16 @@ class Cell:
     spans: Spans
     plant: str = ""      # a fault planted under the timed path (tests only)
 
+    @functools.cached_property
+    def arch(self):
+        """``bench/arch/<model_type>.py`` of the cell's configuration."""
+        return model_module(self.root, self.config, "arch")
+
+    @functools.cached_property
+    def reference(self):
+        """``bench/reference/<model_type>.py``: the plain reference."""
+        return model_module(self.root, self.config, "reference")
+
 
 @dataclasses.dataclass
 class Run:
@@ -171,7 +204,7 @@ class Run:
     tokens_per_step: int             # over all of the cell's chips
     flops_per_token: float
     peak: Optional[dict]             # bench/peaks.json row, None off-chip
-    trace: Optional[dict] = None     # bench/trace.py's dict, traced runs
+    trace: Optional[dict] = None     # bench/scopes.read's dict, traced runs
 
     @property
     def chips(self) -> int:
@@ -250,7 +283,7 @@ def run_cell(root: Path, name: str, seed: int, seconds: float, traced: bool,
     underneath (``bench/program.py``)."""
     import jax
 
-    from bench import compare, flops
+    from bench import compare, scopes
     from bench import trace as trace_lib
 
     bench, entry, spec, config, traffic = load_cell(root, name)
@@ -265,10 +298,11 @@ def run_cell(root: Path, name: str, seed: int, seconds: float, traced: bool,
         import repro  # noqa: F401  the program under test
     except ImportError as e:
         raise RuntimeError(f"the program is not in {root / 'src'}: {e}")
-    meter = CompileMeter()
     spans = Spans()
     cell = Cell(root, name, entry, spec, config, traffic, seed, devices,
                 spans, plant)
+    _ = cell.arch, cell.reference   # a model_type without them fails here
+    meter = CompileMeter()
     driver = load_module(root / "bench" / "drivers" / f"{spec['driver']}.py")
     session = driver.setup(cell)
 
@@ -313,11 +347,11 @@ def run_cell(root: Path, name: str, seed: int, seconds: float, traced: bool,
         files = list(Path(trace_dir).rglob("*.xplane.pb"))
         if len(files) != 1:
             raise RuntimeError(f"{len(files)} trace files in {trace_dir}")
-        tr = trace_lib.load(str(files[0]), SPANS)
+        tr = scopes.load(str(files[0]), SPANS)
         shutil.rmtree(trace_dir, ignore_errors=True)
     run = Run(cell, setup_s, compile_s, t_start, done,
               session.tokens_per_step,
-              flops.train_flops_per_token(config, traffic["seq_len"]),
+              cell.arch.train_flops_per_token(config, traffic["seq_len"]),
               peak, tr)
     metrics = {}
     for m in metrics_for(bench, name, traced):

@@ -1,6 +1,6 @@
 """Model FLOP utilisation of the whole step, in %: model FLOPs per token
-(``bench/flops.py``: forward and backward, no recomputation, causal
-attention) times the window's tokens per second, over the cell's chips
+(``train_flops_per_token`` of ``bench/arch/<model_type>.py``: forward and
+backward, no recomputation, causal attention) times the window's tokens per second, over the cell's chips
 times one chip's bf16 peak (``bench/peaks.json``).  The bf16 peak is the
 ceiling of a float32 matmul at default precision, which the TPU runs as one
 bfloat16 pass."""

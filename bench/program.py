@@ -1,34 +1,12 @@
-"""What the drivers share about the program under test (``src/repro``): its
-``ModelConfig`` built from a configuration file, and the faults that the
-correctness tests plant under the timed path."""
+"""The faults that the correctness tests plant under the timed path of the
+program under test (``src/repro``); the benchmark's own runs plant none.
+What the drivers need of one architecture of the program is in
+``bench/arch/<model_type>.py``."""
 from __future__ import annotations
 
 import contextlib
-import dataclasses
 
 import numpy as np
-
-
-def model_config(c: dict):
-    """The program's ``ModelConfig`` for a configuration file: the registry
-    entry the file names, with every size the file states put in.  So the
-    program runs the file's configuration even where its registry differs
-    (qwen1.5-0.5b's ``rope_theta``)."""
-    from repro.configs import get_config
-    return dataclasses.replace(
-        get_config(c["registry"]),
-        num_layers=c["num_hidden_layers"], d_model=c["hidden_size"],
-        num_heads=c["num_attention_heads"],
-        num_kv_heads=c["num_key_value_heads"], head_dim=c["head_dim"],
-        d_ff=c["intermediate_size"], vocab_size=c["vocab_size"],
-        qkv_bias=c["qkv_bias"], tie_embeddings=c["tie_word_embeddings"],
-        rope_theta=float(c["rope_theta"]), norm_eps=c["rms_norm_eps"],
-        layer_pattern=None)
-
-
-# ---------------------------------------------------------------------------
-# faults planted under the timed path, for the tests that must see
-# ``correct`` come out false; the benchmark's own runs plant none
 
 
 def opt_update(plant: str, update):

@@ -1,4 +1,6 @@
-"""Plain reference of a Qwen2-style decoder (Qwen2, Qwen1.5) and its loss.
+"""Plain reference of a Qwen2-style decoder (Qwen2, Qwen1.5; ``model_type``
+"qwen2") and its loss: ``weighted_loss``, which ``bench/reference/train.py``
+differentiates.
 
 Written from the published architecture (the Hugging Face ``Qwen2Model``),
 not from the program, and imports nothing of it.  Per layer:
@@ -13,7 +15,7 @@ attention scaled by hd^-0.5; a final RMSNorm and logits against the tied
 embedding.  RMSNorm and softmax statistics, and the loss, are taken in
 float32 whatever the storage dtype, as the published code does.
 
-Weights come in the benchmark's layout (``bench/weights.py``): a norm's
+Weights come in the benchmark's layout (``bench/arch/qwen2.py``): a norm's
 stored ``scale`` is an offset, so its weight is 1 + scale.
 
 Sizes: the whole (b, h, s, s) score matrix of one layer is made at once,

@@ -1,7 +1,10 @@
 """Plain reference of the training steps that the cells time: Adam with
 decoupled weight decay behind a warmup-cosine schedule, and rate-weighted
 data-parallel SGD with momentum whose per-device gradients may pass through
-an exact top-k.  Imports nothing of the program.
+an exact top-k.  Imports nothing of the program.  The model is the
+configuration's plain reference, ``bench/reference/<model_type>.py``
+(``harness.model_module``): its ``weighted_loss(params, tokens, labels,
+row_weights, c)`` is all that is differentiated here.
 
 Each function follows the first steps of a run from the seed's weights and
 returns the readings that ``bench/compare.py`` sets beside the program's:
@@ -27,7 +30,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from bench import weights as W
-from bench.reference import qwen
 
 
 def warmup_cosine(step: int, base_lr: float, warmup: int, total: int,
@@ -91,14 +93,15 @@ def _change_norms(p, p0):
 _VG: Dict[tuple, object] = {}
 
 
-def _loss_and_grad(c, dtype):
-    """Jitted value-and-grad of the weighted loss, one per config and
-    dtype, so that following several seeds traces it once."""
-    key = (json.dumps(c, sort_keys=True), jnp.dtype(dtype).name)
+def _loss_and_grad(model, c, dtype):
+    """Jitted value-and-grad of ``model``'s weighted loss, one per model,
+    config and dtype, so that following several seeds traces it once."""
+    key = (model.__file__, json.dumps(c, sort_keys=True),
+           jnp.dtype(dtype).name)
     if key not in _VG:
         def f(params, tokens, labels, w):
             params = jax.tree.map(lambda x: x.astype(dtype), params)
-            return qwen.weighted_loss(params, tokens, labels, w, c)
+            return model.weighted_loss(params, tokens, labels, w, c)
         _VG[key] = jax.jit(jax.value_and_grad(f))
     return _VG[key]
 
@@ -108,13 +111,13 @@ def _precision(dtype):
         "highest" if dtype == jnp.float32 else "default")
 
 
-def follow_adam(c: dict, init, key, batches: List[Dict[str, np.ndarray]],
-                opt: dict, sched: dict, dtype=jnp.float32,
-                fault: str = "") -> dict:
+def follow_adam(model, c: dict, init, key,
+                batches: List[Dict[str, np.ndarray]], opt: dict, sched: dict,
+                dtype=jnp.float32, fault: str = "") -> dict:
     """The single-program ScaDLES step: loss weighted per row by
     ``sample_weights``, then Adam.  ``fault="half_batch"`` leaves out the
     second half of the rows and takes the weighted mean over the rest."""
-    vg = _loss_and_grad(c, dtype)
+    vg = _loss_and_grad(model, c, dtype)
     with _precision(dtype):
         p = _stored(init(key), dtype)
         m, v = _zeros(p), _zeros(p)
@@ -195,9 +198,9 @@ def _dense_accumulate(acc, g, w):
     return jax.tree.map(lambda a, x: a + w * x.astype(jnp.float32), acc, g)
 
 
-def follow_ddp(c: dict, init, key, batches: List[Dict[str, np.ndarray]],
-               n_dev: int, opt: dict, comp: dict, dtype=jnp.float32,
-               fault: str = "") -> dict:
+def follow_ddp(model, c: dict, init, key,
+               batches: List[Dict[str, np.ndarray]], n_dev: int, opt: dict,
+               comp: dict, dtype=jnp.float32, fault: str = "") -> dict:
     """Rate-weighted DDP with SGD-momentum.  Device i's gradient is that of
     the mean token loss over its own rows; the step applies
     sum_i r_i / sum(r) * g_i, with each g_i cut to its exact top-k
@@ -212,7 +215,7 @@ def follow_ddp(c: dict, init, key, batches: List[Dict[str, np.ndarray]],
     own gradient with weight 1, as a step whose collectives were dropped
     leaves the replicated copy.  Returns the readings, the picks and the
     energy gap of each compressed step (mean over devices)."""
-    vg = _loss_and_grad(c, dtype)
+    vg = _loss_and_grad(model, c, dtype)
     with _precision(dtype):
         p = _stored(init(key), dtype)
         n = sum(x.size for x in jax.tree.leaves(p))

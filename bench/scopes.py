@@ -1,21 +1,27 @@
-"""The training step's named scopes and the device clock, from a profiler
-trace: what ``bench/trace.py``'s reductions do not read.
+"""The step's named scopes and the device clock, from a profiler trace:
+what ``bench/trace.py``'s reductions do not read.  The harness reads a
+traced run's trace with ``load``, and the scope metrics
+(``bench/metrics/<scope>_ms_per_step.py``) read it with ``ms_per_step``.
 
 ``read`` gives ``bench/trace.read``'s dict with three more keys:
 
-    {"scopes": {"/device:TPU:0": {op: path}, ...},  # XLA's ``tf_op`` stat
+    {"op_paths": {"/device:TPU:0": [path, ...], ...},  # XLA's ``tf_op``
      "modules": {"/device:TPU:0": [[start_ns, dur_ns, run_id], ...], ...},
      "launches": [[start_ns, dur_ns, name, run_id, device_ordinal], ...]}
 
-``path`` is the op's name stack in the program (``jax.named_scope``), from
-the trace's event metadata (``bench/xspace.py``); an op name that two
-different paths share in one plane (names are unique only within one HLO
-module) maps to the list of them.  ``modules`` are each plane's "XLA
-Modules" events, one per run of a program; ``launches`` the host's
-``DoEnqueueProgram`` and ``CompleteCallbacks`` events of the same runs.
+``op_paths`` gives the path of each event of ``devices``, in order: the
+op's name stack in the program (``jax.named_scope``), from its own
+instruction's metadata in the trace (``bench/xspace.py``), "" where it has
+none.  An event keeps its own path because an op name is unique only
+within one HLO module, and two programs may run in one window.
+``modules`` are each plane's "XLA Modules" events, one per run of a
+program; ``launches`` the host's ``DoEnqueueProgram`` and
+``CompleteCallbacks`` events of the same runs.
 
 - scope time: each op's self time charged to the innermost of the given
-  scope names in its path ("unscoped" when none is), mean over chips;
+  scope names in its path ("unscoped" when none is), mean over chips.  A
+  cell's workload file lists its step's scopes (``"scopes"``); a name it
+  leaves out is no scope, and its ops go to the scope around it;
 - clock offset: the device clock runs early against the host's by an
   amount no run can contradict: a program starts on the device after the
   host began to enqueue it, and ends before the host's completion callback
@@ -48,24 +54,39 @@ from bench import trace, xspace  # noqa: E402
 
 MODULES_LINE = "XLA Modules"
 LAUNCH_EVENTS = ("DoEnqueueProgram", "CompleteCallbacks")
-# the training step's named scopes (``src/repro``), innermost wins
+# the launcher's training step's named scopes (``src/repro``), which the
+# command line reads; a benchmark cell names its own in its workload file
 STEP_SCOPES = ("attention", "mlp", "vocab", "optimizer")
 SPANS = ("window", "train", "input", "dispatch", "metrics_read")
 
 
 def read(path: str, spans: Iterable[str]) -> dict:
-    """``bench/trace.read``, with the ``scopes``, ``modules`` and
+    """``bench/trace.read``, with the ``op_paths``, ``modules`` and
     ``launches`` of the module's docstring."""
     from jax.profiler import ProfileData
-    tr = trace.read(path, spans)
+    data = ProfileData.from_file(path)
+    planes = list(data.planes)       # read twice below
+    tr = trace.from_planes(planes, spans)
+    with open(path, "rb") as f:
+        paths = xspace.event_stats(f.read(), "tf_op", trace.DEVICE_PREFIX)
+    paths = {plane: {text: p[:-1] if p.endswith(":") else p
+                     for text, p in named.items()}
+             for plane, named in paths.items()}
     modules: Dict[str, list] = {}
+    op_paths: Dict[str, List[str]] = {}
     launches: List[list] = []
-    for plane in ProfileData.from_file(path).planes:
+    for plane in planes:
         if plane.name.startswith(trace.DEVICE_PREFIX):
             runs = [[e.start_ns, e.duration_ns, dict(e.stats).get("run_id")]
                     for line in plane.lines if line.name == MODULES_LINE
                     for e in line.events]
             modules[plane.name] = [r for r in runs if r[2] is not None]
+            named = paths.get(plane.name, {})
+            op_paths[plane.name] = [
+                named.get(e.name, "") for line in plane.lines
+                if line.name == trace.OPS_LINE for e in line.events]
+            if len(op_paths[plane.name]) != len(tr["devices"][plane.name]):
+                raise RuntimeError(f"{plane.name}: ops read twice differ")
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 for e in line.events:
@@ -75,23 +96,14 @@ def read(path: str, spans: Iterable[str]) -> dict:
                             launches.append([e.start_ns, e.duration_ns,
                                              e.name, st["run_id"],
                                              st.get("device_ordinal")])
-    with open(path, "rb") as f:
-        paths = xspace.event_stats(f.read(), "tf_op", trace.DEVICE_PREFIX)
-    scopes = {}
-    for plane, named in paths.items():
-        by_op: Dict[str, set] = {}
-        for text, p in named.items():
-            by_op.setdefault(trace.op_name(text), set()).add(
-                p[:-1] if p.endswith(":") else p)
-        scopes[plane] = {op: ps.pop() if len(ps) == 1 else sorted(ps)
-                         for op, ps in by_op.items()}
-    return dict(tr, scopes=scopes, modules=modules, launches=launches)
+    return dict(tr, op_paths=op_paths, modules=modules, launches=launches)
 
 
-def load(path: str) -> dict:
-    """``read`` with a window: the "window" span, or from the first
-    ``train`` span's start to the last one's end."""
-    tr = read(path, SPANS)
+def load(path: str, spans: Iterable[str] = SPANS) -> dict:
+    """``read`` of ``spans`` with a window: the "window" span, or from the
+    first ``train`` span's start to the last one's end.  Neither stays
+    among the host spans."""
+    tr = read(path, set(spans) | {"window", "train"})
     windows = [h for h in tr["host"] if h[2] == "window"]
     if len(windows) > 1:
         raise RuntimeError(f"{len(windows)} 'window' spans in the trace")
@@ -131,18 +143,14 @@ _WRAPPED = re.compile(r"^(?:\w+\()*([^()]+)\)*$")
 def scope_op_seconds(tr: dict, names: Sequence[str]
                      ) -> Dict[str, Dict[str, float]]:
     """``{scope: {op: self seconds}}`` inside the window, mean over chips,
-    each op under ``scope_of`` its path; an op whose name two paths share
-    is an error."""
+    each op under ``scope_of`` its path."""
     lo, hi = tr["window"]
     out: Dict[str, Dict[str, float]] = {}
     n = max(len(tr["devices"]), 1)
     for dev, evs in tr["devices"].items():
-        paths = tr["scopes"].get(dev, {})
-        for op, v in trace.self_seconds(evs, lo, hi).items():
-            path = paths.get(op, "")
-            if isinstance(path, list):
-                raise RuntimeError(f"op {op!r} on {dev} has {len(path)} "
-                                   f"paths in the trace: {path}")
+        keyed = [[s, d, (op, path)] for (s, d, op), path
+                 in zip(evs, tr["op_paths"][dev])]
+        for (op, path), v in trace.self_seconds(keyed, lo, hi).items():
             ops = out.setdefault(scope_of(path, names), {})
             ops[op] = ops.get(op, 0.0) + v / n
     return out
@@ -154,18 +162,38 @@ def scope_seconds(tr: dict, names: Sequence[str]) -> Dict[str, float]:
             for k, v in scope_op_seconds(tr, names).items()}
 
 
-def step_scope_ms(tr: Optional[dict], steps: int
+def step_scope_ms(tr: Optional[dict], names: Sequence[str], steps: int
                   ) -> Optional[Dict[str, float]]:
-    """Device ms per step in each of ``STEP_SCOPES`` and "unscoped"; None
-    without a trace, without scopes in it, or where no op in the window is
-    under any of them (a program that names no scope)."""
-    if tr is None or not steps or not any(tr.get("scopes", {}).values()):
+    """Device ms per step in each of ``names`` and "unscoped"; None
+    without a trace, or where no op in the window is under any of them (a
+    program that names no scope, or a trace without paths)."""
+    if tr is None or not steps:
         return None
-    secs = scope_seconds(tr, STEP_SCOPES)
+    secs = scope_seconds(tr, names)
     if not set(secs) - {"unscoped"}:
         return None
     return {k: secs.get(k, 0.0) / steps * 1e3
-            for k in STEP_SCOPES + ("unscoped",)}
+            for k in tuple(names) + ("unscoped",)}
+
+
+def ms_per_step(run, name: str) -> Optional[float]:
+    """A scope metric's reading of a run (``bench/harness.Run``): device
+    self ms per window step in scope ``name``, mean over chips, each op
+    charged to the innermost of the cell's ``"scopes"``.  None where the
+    cell does not list ``name``, the run was not traced, or no op of the
+    window lies in the scope."""
+    names = tuple(run.cell.spec["scopes"])
+    if name not in names or run.trace is None or not run.done:
+        return None
+    if _last[0] is not run.trace or _last[1] != names:
+        _last[:] = [run.trace, names, scope_seconds(run.trace, names)]
+    secs = _last[2]
+    return secs[name] / len(run.done) * 1e3 if name in secs else None
+
+
+# the last trace, scope names and ``scope_seconds`` that ``ms_per_step``
+# read: a run's readers share one pass over its ops
+_last: list = [None, None, None]
 
 
 def clock_offset_ns(tr: dict) -> Dict[str, dict]:
@@ -225,19 +253,21 @@ def idle_by_span_aligned(tr: dict) -> Dict[str, float]:
     return trace.idle_by_span(dict(tr, devices=shifted))
 
 
-def summary(tr: dict) -> dict:
+def summary(tr: dict, names: Sequence[str] = STEP_SCOPES) -> dict:
     """Everything above for one windowed trace, per window step."""
     steps = steps_in(tr)
     n = max(len(tr["devices"]), 1)
     busy_s = sum(trace.busy(tr).values()) / n
     out = {"steps": steps, "window_s": trace.window_s(tr), "busy_s": busy_s,
-           "scope_ms_per_step": step_scope_ms(tr, steps),
+           "scope_ms_per_step": step_scope_ms(tr, names, steps),
            **clock_summary(clock_offset_ns(tr)),
            "idle_gaps": trace.top(trace.idle_by_span(tr)),
            "idle_gaps_aligned": trace.top(idle_by_span_aligned(tr))}
     if out["scope_ms_per_step"] is not None:
-        rest = scope_op_seconds(tr, STEP_SCOPES).get("unscoped", {})
-        paths = next(iter(tr["scopes"].values()))
+        rest = scope_op_seconds(tr, names).get("unscoped", {})
+        dev = next(iter(tr["devices"]))
+        paths = {op: p for (_, _, op), p
+                 in zip(tr["devices"][dev], tr["op_paths"][dev])}
         out["unscoped_ops"] = [[op, v, paths.get(op, "")]
                                for op, v in trace.top(rest)]
     return out

@@ -113,3 +113,53 @@ def test_ddp_cells_hold_picks_and_few_cells_take_four_chips():
                            f"{cell}.json").read_text())
         if spec["driver"] == "ddp_adaptive":
             assert spec["limits"]["picks_differ"] == 0, cell
+
+
+WORKLOAD_FILES = sorted(p.stem for p in (ROOT / "bench" / "workloads")
+                        .glob("*.json"))
+
+
+@pytest.mark.parametrize("cell", WORKLOAD_FILES)
+def test_workload_files_name_their_steps_scopes(cell):
+    spec = json.loads((ROOT / "bench" / "workloads" /
+                       f"{cell}.json").read_text())
+    assert isinstance(spec["scopes"], list) and spec["scopes"]
+    assert len(set(spec["scopes"])) == len(spec["scopes"])
+    assert all(NAME.match(s) for s in spec["scopes"])
+
+
+def test_scope_metrics_name_only_cells_with_their_scope():
+    readers = [m for m in BENCH["per_layer"]
+               if m["name"].endswith("_ms_per_step")
+               and (ROOT / "bench" / "metrics" / f"{m['name']}.py")
+               .read_text().count("scopes.ms_per_step(")]
+    assert {m["name"] for m in readers} >= {
+        f"{s}_ms_per_step" for s in ("attention", "mlp", "vocab",
+                                     "optimizer", "topk", "scatter_add")}
+    for m in readers:
+        scope = m["name"][:-len("_ms_per_step")]
+        assert m["source"] == "device_trace" and m["unit"] == "ms"
+        for cell in m["workloads"]:
+            spec = json.loads((ROOT / "bench" / "workloads" /
+                               f"{cell}.json").read_text())
+            assert scope in spec["scopes"], (m["name"], cell)
+
+
+# the sizes a Qwen2 configuration file states; only the qwen2 architecture
+# and reference modules, the configuration files and tests read them
+ARCH_KEYS = ("registry", "hidden_size", "intermediate_size",
+             "num_hidden_layers", "num_attention_heads",
+             "num_key_value_heads", "head_dim", "qkv_bias", "rope_theta",
+             "rms_norm_eps", "tie_word_embeddings")
+ARCH_FILES = {"bench/arch/qwen2.py", "bench/reference/qwen2.py"}
+
+
+def test_only_the_architecture_modules_read_its_shapes():
+    for path in sorted((ROOT / "bench").rglob("*.py")):
+        rel = path.relative_to(ROOT).as_posix()
+        if rel in ARCH_FILES or rel.startswith("bench/tests/"):
+            continue
+        text = path.read_text()
+        for key in ARCH_KEYS:
+            assert f'"{key}"' not in text and f"'{key}'" not in text, \
+                (rel, key)

@@ -15,13 +15,19 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import program, scopes, trace, xspace
+from bench import harness, scopes, trace, xspace
+from bench.arch import qwen2
 from bench.tests import tiny
 
 DATA = Path(__file__).parent / "data"
 SMALL = DATA / "tpu_small.xplane.pb"
 SCOPED = DATA / "tpu_scoped.xplane.pb"
-DDP_SCOPES = scopes.STEP_SCOPES + ("topk", "scatter_add")
+WORKLOADS = Path(__file__).resolve().parents[1] / "workloads"
+METRICS = Path(__file__).resolve().parents[1] / "metrics"
+TRAIN_SCOPES = tuple(json.loads((WORKLOADS / "train.qwen1.5-0.5b.seq2048.json"
+                                 ).read_text())["scopes"])
+DDP_SCOPES = tuple(json.loads((WORKLOADS / "ddp4.qwen2-0.5b.adaptive.json"
+                               ).read_text())["scopes"])
 SEQ, ROWS = 32, 4
 
 # one HLO instruction: its name, opcode and metadata
@@ -47,7 +53,7 @@ def train_hlo() -> str:
     from repro.launch.train import train_ctx
     from repro.optim import warmup_cosine
     from repro.train import make_train_step
-    cfg = program.model_config(tiny.TINY_CONFIG)
+    cfg = qwen2.model_config(tiny.TINY_CONFIG)
     p, o, batch, opt_update = _shapes(cfg)
     fn = jax.jit(make_train_step(cfg, train_ctx(SEQ), opt_update,
                                  warmup_cosine(1e-3, 2, 10)),
@@ -64,7 +70,7 @@ def ddp_hlo() -> str:
     from repro.launch.train import train_ctx
     from repro.optim import warmup_cosine
     from repro.train.ddp import make_ddp_steps
-    cfg = program.model_config(tiny.TINY_CONFIG)
+    cfg = qwen2.model_config(tiny.TINY_CONFIG)
     p, o, batch, opt_update = _shapes(cfg)
     mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
     _, comp, _, _ = make_ddp_steps(cfg, train_ctx(SEQ), mesh, opt_update,
@@ -103,7 +109,7 @@ def train_text():
 @pytest.mark.parametrize("which", ["train", "ddp"])
 def test_matmuls_gathers_and_scatters_lie_in_scopes(which, train_text):
     hlo = train_text if which == "train" else ddp_hlo()
-    names = scopes.STEP_SCOPES if which == "train" else DDP_SCOPES
+    names = TRAIN_SCOPES if which == "train" else DDP_SCOPES
     found = set()
     for name, opcode, path in instructions(hlo):
         scope = scopes.scope_of(path, names)
@@ -163,12 +169,19 @@ def test_xspace_rejects_a_cut_file():
         xspace.event_stats(data[:len(data) // 2], "tf_op", "/device:TPU:")
 
 
+def _paths_by_op(tr, dev="/device:TPU:0"):
+    return {op: p for (_, _, op), p in zip(tr["devices"][dev],
+                                           tr["op_paths"][dev])}
+
+
 def test_read_keeps_each_ops_path():
     tr = scopes.read(str(SMALL), ())
-    assert tr["scopes"]["/device:TPU:0"]["fusion"] == \
-        "jit(<lambda>)/dot_general"
-    # copies carry no path, and stay out of the map
-    assert "copy-start" not in tr["scopes"]["/device:TPU:0"]
+    dev = "/device:TPU:0"
+    assert len(tr["op_paths"][dev]) == len(tr["devices"][dev])
+    paths = _paths_by_op(tr)
+    assert paths["fusion"] == "jit(<lambda>)/dot_general"
+    # copies carry no path
+    assert paths["copy-start"] == ""
 
 
 @pytest.mark.parametrize("path,want", [
@@ -188,28 +201,32 @@ def test_scope_of_takes_the_innermost_name(path, want):
     assert scopes.scope_of(path, scopes.STEP_SCOPES) == want
 
 
-def _hand_trace():
+def _hand_trace(**changed):
     # chip a: a while loop (0..100) around an attention and an mlp op, a
     # vocab op from the backward, an unscoped op, and an op with no path;
     # chip b: one optimizer op.  The window leaves out the op at 300.
-    return {"window": [0, 250], "host": [],
-            "devices": {
-                "/device:TPU:0": [[0, 100, "while.1"], [10, 30, "fusion.1"],
-                                  [50, 20, "fusion.2"], [120, 10, "fusion.3"],
-                                  [140, 5, "fusion.4"], [150, 8, "copy.1"],
-                                  [300, 9, "fusion.1"]],
-                "/device:TPU:1": [[0, 40, "fusion.9"]]},
-            "scopes": {
-                "/device:TPU:0": {
-                    "while.1": "jit(s)/transpose(jvp())/while",
-                    "fusion.1": "jit(s)/jvp()/while/body/closed_call/"
-                                "attention/attention/dot_general",
-                    "fusion.2": "jit(s)/transpose(jvp())/while/body/"
-                                "closed_call/checkpoint/mlp/dot_general",
-                    "fusion.3": "jit(s)/transpose(jvp(vocab))/"
-                                "jit(_take)/scatter-add",
-                    "fusion.4": "jit(s)/transpose(jvp())/add_any"},
-                "/device:TPU:1": {"fusion.9": "jit(s)/optimizer/add"}}}
+    # ``changed`` gives chip a's op ``fusion_2`` (``fusion.2``) another path.
+    devices = {
+        "/device:TPU:0": [[0, 100, "while.1"], [10, 30, "fusion.1"],
+                          [50, 20, "fusion.2"], [120, 10, "fusion.3"],
+                          [140, 5, "fusion.4"], [150, 8, "copy.1"],
+                          [300, 9, "fusion.1"]],
+        "/device:TPU:1": [[0, 40, "fusion.9"]]}
+    paths = {
+        "/device:TPU:0": {
+            "while.1": "jit(s)/transpose(jvp())/while",
+            "fusion.1": "jit(s)/jvp()/while/body/closed_call/"
+                        "attention/attention/dot_general",
+            "fusion.2": "jit(s)/transpose(jvp())/while/body/"
+                        "closed_call/checkpoint/mlp/dot_general",
+            "fusion.3": "jit(s)/transpose(jvp(vocab))/"
+                        "jit(_take)/scatter-add",
+            "fusion.4": "jit(s)/transpose(jvp())/add_any",
+            **{k.replace("_", "."): v for k, v in changed.items()}},
+        "/device:TPU:1": {"fusion.9": "jit(s)/optimizer/add"}}
+    return {"window": [0, 250], "host": [], "devices": devices,
+            "op_paths": {d: [paths[d].get(op, "") for _, _, op in evs]
+                         for d, evs in devices.items()}}
 
 
 def test_scope_seconds_charges_self_time_to_the_innermost_scope():
@@ -229,29 +246,23 @@ def test_scope_seconds_charges_self_time_to_the_innermost_scope():
         sum(trace.op_seconds(_hand_trace()).values()))
 
 
-def test_an_op_name_with_two_paths_is_an_error():
-    tr = _hand_trace()
-    tr["scopes"]["/device:TPU:0"]["fusion.2"] = ["jit(a)/mlp/dot_general",
-                                                 "jit(b)/add"]
-    with pytest.raises(RuntimeError, match="fusion.2"):
-        scopes.scope_seconds(tr, scopes.STEP_SCOPES)
-
-
 def test_step_scope_ms_gives_nothing_without_scopes():
     tr = _hand_trace()
-    assert scopes.step_scope_ms(None, 2) is None
-    assert scopes.step_scope_ms(tr, 0) is None
-    assert scopes.step_scope_ms(dict(tr, scopes={}), 2) is None
-    # a program that names no scope: every path, none of the names
-    plain = {d: {op: "jit(s)/add" for op in ops}
-             for d, ops in tr["scopes"].items()}
-    assert scopes.step_scope_ms(dict(tr, scopes=plain), 2) is None
+    names = scopes.STEP_SCOPES
+    assert scopes.step_scope_ms(None, names, 2) is None
+    assert scopes.step_scope_ms(tr, names, 0) is None
+    # a trace without paths, and a program that names no scope: every
+    # path, none of the names
+    for path in ("", "jit(s)/add"):
+        plain = {d: [path] * len(ps) for d, ps in tr["op_paths"].items()}
+        assert scopes.step_scope_ms(dict(tr, op_paths=plain), names,
+                                    2) is None
 
 
 def test_step_scope_ms_adds_up_to_the_scoped_time():
     tr = _hand_trace()
     secs = scopes.scope_seconds(tr, scopes.STEP_SCOPES)
-    assert scopes.step_scope_ms(tr, 2) == {
+    assert scopes.step_scope_ms(tr, scopes.STEP_SCOPES, 2) == {
         k: pytest.approx(v / 2 * 1e3) for k, v in secs.items()}
 
 
@@ -264,7 +275,7 @@ def scoped():
 
 
 def test_a_chip_trace_names_the_matmul_attention(scoped):
-    paths = scoped["scopes"]["/device:TPU:0"]
+    paths = _paths_by_op(scoped)
     matmuls = {op: p for op, p in paths.items() if "dot_general" in p}
     assert matmuls
     for op, p in matmuls.items():
@@ -321,3 +332,85 @@ def test_the_command_reduces_a_launcher_capture(tmp_path, capsys):
 def test_the_command_needs_a_window():
     with pytest.raises(RuntimeError, match="window"):
         scopes.load(str(SCOPED))
+
+
+def _scope_reader(name):
+    return harness.load_module(METRICS / f"{name}_ms_per_step.py")
+
+
+def _run(tr, names, steps):
+    """What a scope reader needs of ``bench/harness.Run``."""
+    from types import SimpleNamespace
+    return SimpleNamespace(trace=tr, done=[0.0] * steps,
+                           cell=SimpleNamespace(spec={"scopes": list(names)}))
+
+
+@pytest.mark.parametrize("names", [TRAIN_SCOPES, DDP_SCOPES,
+                                   ("mlp", "vocab", "optimizer")])
+def test_scope_readers_and_unscoped_add_up_to_busy_time(scoped, names):
+    run = _run(scoped, names, 3)
+    got = {s: _scope_reader(s).read(run) for s in DDP_SCOPES}
+    # a reader reads nothing where the cell does not list its scope, or
+    # where no op of the window lies in it
+    assert {s for s, v in got.items() if v is not None} == (
+        {"attention"} & set(names))
+    unscoped = scopes.scope_seconds(scoped, names)["unscoped"] / 3 * 1e3
+    busy = trace.busy(scoped)["/device:TPU:0"] / 3 * 1e3
+    assert sum(v for v in got.values() if v) + unscoped == pytest.approx(
+        busy, rel=1e-9)
+
+
+def test_a_scope_left_out_goes_to_the_scope_around_it():
+    tr = _hand_trace(fusion_2="jit(s)/transpose(jvp())/optimizer/"
+                              "checkpoint/mlp/dot_general")
+    run = _run(tr, TRAIN_SCOPES, 2)
+    assert _scope_reader("mlp").read(run) == pytest.approx(10e-9 / 2 * 1e3)
+    assert _scope_reader("optimizer").read(run) == pytest.approx(
+        20e-9 / 2 * 1e3)
+    # without "mlp" its op is the optimizer's; without "attention" too,
+    # the attention ops, in no other listed scope, go to "unscoped"
+    run = _run(tr, ("vocab", "optimizer"), 2)
+    assert _scope_reader("mlp").read(run) is None
+    assert _scope_reader("attention").read(run) is None
+    assert _scope_reader("optimizer").read(run) == pytest.approx(
+        30e-9 / 2 * 1e3)
+    assert scopes.scope_seconds(tr, ("vocab", "optimizer"))["unscoped"] == \
+        pytest.approx((50 + 5 + 8 + 30) / 2 * 1e-9)
+
+
+def test_scope_readers_need_a_trace():
+    run = _run(None, TRAIN_SCOPES, 3)
+    assert _scope_reader("attention").read(run) is None
+    assert _scope_reader("attention").read(
+        _run(_hand_trace(), TRAIN_SCOPES, 0)) is None
+
+
+def test_an_op_name_of_two_programs_takes_each_events_own_path():
+    # two programs in one window each have a fusion.1: the trace's
+    # per-event paths keep them apart where the op name cannot
+    tr = {"window": [0, 100], "host": [],
+          "devices": {"/device:TPU:0": [[0, 10, "fusion.1"],
+                                        [20, 30, "fusion.1"]]},
+          "op_paths": {"/device:TPU:0": ["jit(a)/mlp/dot",
+                                         "jit(b)/topk/sort"]}}
+    assert scopes.scope_op_seconds(tr, DDP_SCOPES) == {
+        "mlp": {"fusion.1": pytest.approx(10e-9)},
+        "topk": {"fusion.1": pytest.approx(30e-9)}}
+
+
+def test_a_runs_scope_readers_share_one_pass(monkeypatch):
+    calls = []
+    real = scopes.scope_seconds
+    monkeypatch.setattr(scopes, "scope_seconds",
+                        lambda tr, names: calls.append(names) or real(tr, names))
+    tr = _hand_trace()
+    run = _run(tr, TRAIN_SCOPES, 2)
+    got = {s: _scope_reader(s).read(run) for s in TRAIN_SCOPES}
+    assert calls == [tuple(TRAIN_SCOPES)]
+    assert got == {s: pytest.approx(v / 2 * 1e3)
+                   for s, v in real(tr, TRAIN_SCOPES).items()
+                   if s != "unscoped"}
+    # another trace, or the same one under other names, is read anew
+    _scope_reader("mlp").read(_run(_hand_trace(), TRAIN_SCOPES, 2))
+    _scope_reader("mlp").read(_run(tr, ("mlp",), 2))
+    assert len(calls) == 3

@@ -1,7 +1,10 @@
 """A copy of the benchmark at a size the CPU runs in seconds: the bench
-tree and BENCHMARK.json copied to a temporary root, plus files for two tiny
-cells (a 2-layer, 64-wide Qwen2-style model) that the harness has never
-seen.  Nothing in the harness names them."""
+tree and BENCHMARK.json copied to a temporary root, plus files for tiny
+cells that the harness has never seen: two of a 2-layer, 64-wide
+Qwen2-style model, and two of an architecture that only this copy holds
+(``tiny_swa/``: its ``bench/arch`` and ``bench/reference`` modules, and a
+reader of the FLOPs per token the harness took from it).  Nothing in the
+harness names them."""
 from __future__ import annotations
 
 import json
@@ -20,16 +23,31 @@ TINY_CONFIG = {
     "reduced": [],
 }
 
+# the same sizes under an architecture of its own: no q/k/v biases, every
+# layer over a sliding window
+TINY_SWA = dict({k: v for k, v in TINY_CONFIG.items()
+                 if k not in ("registry", "qkv_bias")},
+                name="tiny-swa", model_type="tiny_swa", sliding_window=8)
+SWA_FILES = {"arch.py": "arch/tiny_swa.py",
+             "reference.py": "reference/tiny_swa.py",
+             "flops_per_token.py": "metrics/flops_per_token.py"}
 
+
+TRAIN_TRAFFIC = {"rows": 4, "seq_len": 32, "determinism": 0.8,
+                 "streams": {"dist": "S1", "devices": 8,
+                             "weights": "per_sample"}}
+DDP_TRAFFIC = {"rows": 8, "seq_len": 32, "determinism": 0.8,
+               "streams": {"dist": "S1", "devices": 4,
+                           "weights": "per_device"}}
 # each tiny cell mirrors a real one: its driver, optimizer and limits
-MIRROR = {"tiny.train": ("train.qwen1.5-0.5b.seq2048",
-                         {"rows": 4, "seq_len": 32, "determinism": 0.8,
-                          "streams": {"dist": "S1", "devices": 8,
-                                      "weights": "per_sample"}}),
-          "tiny.ddp": ("ddp4.qwen2-0.5b.adaptive",
-                       {"rows": 8, "seq_len": 32, "determinism": 0.8,
-                        "streams": {"dist": "S1", "devices": 4,
-                                    "weights": "per_device"}})}
+MIRROR = {"tiny.train": ("train.qwen1.5-0.5b.seq2048", TINY_CONFIG,
+                         TRAIN_TRAFFIC),
+          "tiny.ddp": ("ddp4.qwen2-0.5b.adaptive", TINY_CONFIG,
+                       DDP_TRAFFIC),
+          "tiny.swa.train": ("train.qwen1.5-0.5b.seq2048", TINY_SWA,
+                             TRAIN_TRAFFIC),
+          "tiny.swa.ddp": ("ddp4.qwen2-0.5b.adaptive", TINY_SWA,
+                           DDP_TRAFFIC)}
 
 
 def make_root(tmp: Path) -> Path:
@@ -38,29 +56,39 @@ def make_root(tmp: Path) -> Path:
     shutil.copytree(REPO / "bench", root / "bench",
                     ignore=shutil.ignore_patterns("tests", "__pycache__"))
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
-    (root / "bench" / "configs" / "tiny-qwen2.json").write_text(
-        json.dumps(TINY_CONFIG))
-    for name, (real, traffic) in MIRROR.items():
+    for c in (TINY_CONFIG, TINY_SWA):
+        (root / "bench" / "configs" / f"{c['name']}.json").write_text(
+            json.dumps(c))
+    for src, dst in SWA_FILES.items():
+        shutil.copy(Path(__file__).parent / "tiny_swa" / src,
+                    root / "bench" / dst)
+    bench["end_to_end"].append({
+        "name": "flops_per_token", "unit": "FLOP", "better": "lower",
+        "bound": 0.01, "source": "host_clock", "workloads": []})
+    for name, (real, config, traffic) in MIRROR.items():
         spec = json.loads((REPO / "bench" / "workloads" /
                            f"{real}.json").read_text())
         chips = spec["chips"]
-        spec.update(config="tiny-qwen2", traffic=name)
+        spec.update(config=config["name"], traffic=name)
         (root / "bench" / "traffic" / f"{name}.json").write_text(
             json.dumps(traffic))
         (root / "bench" / "workloads" / f"{name}.json").write_text(
             json.dumps(spec))
-        bench["workloads"].append({"name": name, "config": "tiny-qwen2",
+        bench["workloads"].append({"name": name, "config": config["name"],
                                    "traffic": name, "chips": chips,
                                    "why": "test"})
         for m in bench["end_to_end"] + bench["per_layer"]:
-            if real in m.get("workloads", ()):
+            if real in m.get("workloads", ()) or (
+                    m["name"] == "flops_per_token" and config is TINY_SWA):
                 m["workloads"].append(name)
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
     return root
 
 
-def run_plants(name: str, plants, seconds: float = 0.2) -> dict:
-    """``correct`` and the compared numbers of one tiny run per plant."""
+def run_plants(runs: dict, seconds: float = 0.2) -> dict:
+    """``correct``, the metrics and the compared numbers of one tiny run
+    per plant: ``runs`` is ``{cell: [plant, ...]}``, the result
+    ``{cell: {plant: ...}}``."""
     import sys
     import tempfile
     import time
@@ -71,14 +99,18 @@ def run_plants(name: str, plants, seconds: float = 0.2) -> dict:
     out = {}
     with tempfile.TemporaryDirectory() as tmp:
         root = make_root(Path(tmp))
-        for plant in plants:
-            res = harness.run_cell(root, name, 20261016, seconds, False,
-                                   time.perf_counter(), require_chip=False,
-                                   plant=plant)
-            out[plant] = {"correct": res["correct"],
-                          "attempted": res["attempted"],
-                          "metrics": sorted(res["metrics"]),
-                          "checks": res["checks"]}
+        for name, plants in runs.items():
+            for plant in plants:
+                res = harness.run_cell(root, name, 20261016, seconds, False,
+                                       time.perf_counter(),
+                                       require_chip=False, plant=plant)
+                out.setdefault(name, {})[plant] = {
+                    "correct": res["correct"],
+                    "attempted": res["attempted"],
+                    "metrics": sorted(res["metrics"]),
+                    "values": {k: v["value"]
+                               for k, v in res["metrics"].items()},
+                    "checks": res["checks"]}
     return out
 
 
@@ -119,4 +151,4 @@ if __name__ == "__main__":
         print(json.dumps(run_controls(sys.argv[2], [3, 4, 5],
                                       sys.argv[3].split(","))))
     else:
-        print(json.dumps(run_plants(sys.argv[1], sys.argv[2].split(","))))
+        print(json.dumps(run_plants(json.loads(sys.argv[1]))))

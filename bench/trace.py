@@ -1,7 +1,8 @@
 """From a profiler trace to the numbers the per-layer metrics read.
 
-``load`` turns the ``.xplane.pb`` that ``jax.profiler`` writes into a small
-dict of plain lists, and the reductions below work on that dict alone:
+``read`` turns the ``.xplane.pb`` that ``jax.profiler`` writes into a small
+dict of plain lists (``bench/scopes.load`` adds the window), and the
+reductions below work on that dict alone:
 
     {"window": [start_ns, end_ns],           # the harness's "window" span
      "devices": {"/device:TPU:0": [[start_ns, dur_ns, op], ...], ...},
@@ -45,10 +46,15 @@ def read(path: str, spans: Iterable[str]) -> dict:
     """Every "XLA Ops" event of each TPU plane, and every host event named
     in ``spans``: ``{"devices": {...}, "host": [...]}``."""
     from jax.profiler import ProfileData
+    return from_planes(ProfileData.from_file(path).planes, spans)
+
+
+def from_planes(planes, spans: Iterable[str]) -> dict:
+    """``read`` of a trace's planes (``ProfileData.planes``)."""
     keep = set(spans)
     devices: Dict[str, list] = {}
     host: List[list] = []
-    for plane in ProfileData.from_file(path).planes:
+    for plane in planes:
         if plane.name.startswith(DEVICE_PREFIX):
             evs = []
             for line in plane.lines:
@@ -61,17 +67,6 @@ def read(path: str, spans: Iterable[str]) -> dict:
                 host.extend([e.start_ns, e.duration_ns, e.name]
                             for e in line.events if e.name in keep)
     return {"devices": devices, "host": host}
-
-
-def load(path: str, spans: Iterable[str]) -> dict:
-    """``read``, with the window taken from the "window" host span."""
-    tr = read(path, set(spans) | {"window"})
-    windows = [h for h in tr["host"] if h[2] == "window"]
-    if len(windows) != 1:
-        raise RuntimeError(f"{len(windows)} 'window' spans in the trace")
-    s, d, _ = windows[0]
-    return {"window": [s, s + d], "devices": tr["devices"],
-            "host": [h for h in tr["host"] if h[2] != "window"]}
 
 
 def _union(intervals: Sequence[Tuple[float, float]], lo: float, hi: float):
