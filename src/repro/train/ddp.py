@@ -9,6 +9,12 @@ EWMA controller (core.compression.AdaptiveCompressor) pick per iteration:
   dense_step      — grads -> psum(r_i * g_i)                 (Eqn 4b on wire)
   compressed_step — grads -> top-k -> all_gather(r_i*vals, idx) -> scatter-add
 
+The top-k is ``core.compression.global_topk``: exact, with no sort — a
+bisection over the magnitudes' bits finds the k-th largest, and the kept
+entries are packed with their indices in ascending order (on the TPU by the
+Pallas kernel ``kernels/topk_compact.py``, elsewhere by ``jnp.nonzero``; the
+platform picks).  The energy gap is taken from the packed values.
+
 The compressed program's collectives move 2k*(D-1)/D * D ~ 2kD words instead
 of 2G(D-1)/D — the reduction is directly visible in the HLO collective bytes
 (benchmarks/compression_wire.py).  Meshes here are data-parallel only, like
@@ -85,9 +91,7 @@ def make_ddp_steps(cfg: ModelConfig, ctx: RunCtx, mesh, opt_update: Callable,
         flat, _ = comp_lib.flatten_grads(grads)
         with jax.named_scope("topk"):
             vals, idx = comp_lib.global_topk(flat, k)
-        with jax.named_scope("scatter_add"):
-            dense = comp_lib.densify(vals, idx, n_floats)
-        gap = comp_lib.energy_gap(flat, dense)
+        gap = comp_lib.energy_gap(flat, vals)
         # pack (r_i * values, indices) and all-gather across devices
         vals = vals * w
         for ax in dp:

@@ -16,11 +16,13 @@ import jax.numpy as jnp  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
 from repro.configs import get_config  # noqa: E402
+from repro.core import compression  # noqa: E402
 from repro.kernels.block_topk import block_topk, fused_sgdm  # noqa: E402
 from repro.kernels.flash_attention import flash_attention_fwd  # noqa: E402
 from repro.kernels.flash_decode import (flash_decode,  # noqa: E402
                                         flash_decode_paged)
 from repro.kernels.flash_train import flash_bwd, flash_fwd  # noqa: E402
+from repro.kernels.topk_compact import topk_compact  # noqa: E402
 
 CFG = get_config("qwen2-0.5b")
 H, KVH, HD = CFG.num_heads, CFG.num_kv_heads, CFG.head_dim
@@ -111,6 +113,28 @@ def test_block_topk_compiles_for_v5e(one_chip):
     g2d = jax.ShapeDtypeStruct((-(-n_params // 8192) * 8, 1024), jnp.float32,
                                sharding=one_chip)
     _compile(lambda g: block_topk(g, 102, interpret=False), g2d)
+
+
+def test_topk_compact_compiles_for_v5e(one_chip):
+    """The packing kernel over 4M floats (not a multiple of its block),
+    keeping a tenth, as the compressed DDP program does."""
+    n = 4_000_000
+    f = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)
+    s = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    _compile(lambda f, t, r: topk_compact(f, t, r, k=n // 10,
+                                          interpret=False), f, s, s)
+
+
+def test_global_topk_for_v5e_has_no_sort_gather_or_scatter(one_chip,
+                                                            monkeypatch):
+    """On the TPU the exact top-k is reductions and the packing kernel."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n = 3_000_000
+    f = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)
+    hlo = _compile(lambda f: compression.global_topk(f, n // 10),
+                   f).as_text()
+    for op in (" sort(", " gather(", " scatter(", "TopK", "top_k"):
+        assert op not in hlo, op
 
 
 def test_fused_sgdm_compiles_for_v5e(one_chip):
