@@ -22,8 +22,10 @@ rates that synchronous (balanced-mix) commits handle fine:
 
 Rows carry realised mean label divergence, commit throughput and staleness
 so the frontier is attributable.  Results land in
-``artifacts/fleet/noniid_sweep.json``; the perf gate pins the headline
-(``noniid_strict_advantage_x``) so the regime can't silently vanish.
+``artifacts/fleet/noniid_sweep.json``; the α = 0.05 semi-sync vs async pair
+is held by ``tests/test_streamdata.py::
+test_semi_sync_beats_async_under_heavy_label_skew`` so the regime can't
+silently vanish.
 """
 import time
 

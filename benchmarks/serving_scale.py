@@ -23,12 +23,13 @@ Five cells, one artifact (``artifacts/serve/serving_scale.json``):
    prompts) through ``PrefixSimRunner`` lanes at equal ``num_pages``,
    sharing on vs off: refcounted prefix pages + prefill-skip must buy
    deadline-met goodput and TTFT p95 where the page pool is the binding
-   constraint (perf-gate pinned: ``prefix_hit_rate``,
-   ``shared_goodput_win_x``, ``pages_saved_frac``).
+   constraint (``prefix_hit_rate``, ``shared_goodput_win_x``,
+   ``pages_saved_frac``).
 
-Cells 1-3 and 5 run on the synthetic stress cost model (same constants the
-perf gate pins) so the regime is the interesting one on any host; the real-
-runner cell also reports this host's measured base+token prefill fit.
+Cells 1-3 and 5 run on the synthetic stress cost model (the constants
+``tests/test_serve_scale.py`` uses) so the regime is the interesting one on
+any host; their goodputs and latencies are sim-seconds.  The real-runner
+cell also reports this host's measured base+token prefill fit.
 """
 import argparse
 
@@ -42,7 +43,7 @@ MAX_BATCH = 4
 HORIZON = 8.0
 CHUNKS = (None, 16, 32, 64, 128)
 RUNNERS = (1, 2, 4)
-# the stress regime the perf gate pins: decode 10ms, prefill 0.5ms/token
+# the stress regime: decode 10ms, prefill 0.5ms/token
 # + 2ms dispatch base (the chunking tradeoff needs a real base cost)
 COST = StepCostModel(decode_step_s=0.01, prefill_token_s=5e-4,
                      prefill_base_s=2e-3)
@@ -167,8 +168,8 @@ def bench_real_paged_runner():
 
 
 def shared_prefix_trace(horizon=HORIZON):
-    """The Zipf shared-template near-overload trace (also the perf-gate
-    workload): long prompts whose first 192 tokens are one of 4 templates."""
+    """The Zipf shared-template near-overload trace: long prompts whose
+    first 192 tokens are one of 4 templates."""
     return RequestStream(dist="S2", n_clients=16, prompt_len=256,
                          max_new_tokens=16, slo_ttft_s=0.5, slo_tpot_s=0.05,
                          seed=0, n_templates=4, template_prefix_len=192,

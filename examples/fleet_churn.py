@@ -21,8 +21,8 @@ Run:  PYTHONPATH=src python examples/fleet_churn.py
       PYTHONPATH=src python examples/fleet_churn.py --track churn.jsonl
 
 ``--track`` attaches a ``repro.obs.JsonTracker`` to every trainer in the
-demo: per-round records (loss, MFU, wire bytes, staleness) and fleet commit
-telemetry land on one JSONL run ledger, stamped with git SHA + seed.
+demo: per-round records (loss, step flops, wire bytes, staleness) and fleet
+commit telemetry land on one JSONL run ledger, stamped with git SHA + seed.
 """
 import argparse
 

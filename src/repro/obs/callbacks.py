@@ -3,8 +3,9 @@
 The trainer, fleet engine and serving scheduler stay almost untouched by
 observability — each holds a tracker and, when it is active, hands its
 already-computed host-side state to the helpers here.  Everything derived
-(MFU, wire bytes, samples/s) is computed *from* that state, never by adding
-work to the jitted path — the zero-perturbation rule:
+(step flops, wire bytes, samples per sim-second) is computed *from* that
+state, never by adding work to the jitted path — the zero-perturbation
+rule:
 
 * no extra jitted computation, ever (flops come from a one-time lowering of
   the same program jit compiles anyway);
@@ -13,8 +14,10 @@ work to the jitted path — the zero-perturbation rule:
 
 Record kinds (one namespace per producer, shared ledger):
 
-* ``train_round``  — per-commit trainer record: loss, MFU, samples/s, wire
-  bytes, staleness/buffer stats (``ScaDLESTrainer``).
+* ``train_round``  — per-commit trainer record: loss, step flops, samples
+  per sim-second, wire bytes, staleness/buffer stats (``ScaDLESTrainer``).
+  ``dt_s`` and ``samples_per_s`` are on the simulated clock, not a
+  measured speed.
 * ``train_summary`` — end-of-run ``trainer.summary()``.
 * ``fleet_round``  — per-commit engine telemetry (``FleetEngine.round``).
 * ``serve_event``  — request lifecycle: admit / first_token / finish /
@@ -25,7 +28,6 @@ from __future__ import annotations
 
 from typing import Any, Dict, Mapping, Optional
 
-from repro.obs.mfu import DEVICE_PEAK_FLOPS, lowered_flops, mfu
 from repro.obs.tracker import NOOP, Tracker
 
 TRAIN_ROUND = "train_round"
@@ -33,6 +35,23 @@ TRAIN_SUMMARY = "train_summary"
 FLEET_ROUND = "fleet_round"
 SERVE_EVENT = "serve_event"
 SERVE_SUMMARY = "serve_summary"
+
+
+def lowered_flops(fn, *args) -> float:
+    """Flops of one call of jitted ``fn`` at ``args``, from optimized HLO.
+
+    Primary source is ``repro.dist.hlo_cost.analyze_hlo`` (matches XLA's
+    ``cost_analysis`` and multiplies ``while`` bodies by their trip count);
+    where the walker counts nothing, ``Compiled.cost_analysis()`` answers.
+    Lowering or compile errors propagate: a step that cannot be compiled is
+    a fault, not a missing metric.
+    """
+    from repro.dist.hlo_cost import analyze_hlo
+    compiled = fn.lower(*args).compile()
+    flops = float(analyze_hlo(compiled.as_text())["flops"])
+    if flops == 0.0:
+        flops = float(compiled.cost_analysis().get("flops", 0.0))
+    return flops
 
 
 def ring_wire_bytes_per_device(n_devices: int, floats_on_wire: float) -> float:
@@ -53,11 +72,9 @@ class RoundObserver:
     is free and nothing is ever lowered.
     """
 
-    def __init__(self, tracker: Tracker, *, n_devices: int,
-                 peak_flops: float = DEVICE_PEAK_FLOPS) -> None:
+    def __init__(self, tracker: Tracker, *, n_devices: int) -> None:
         self.tracker = tracker if tracker is not None else NOOP
         self.n_devices = int(n_devices)
-        self.peak_flops = float(peak_flops)
         self._flops_cache: Dict[int, Optional[float]] = {}
 
     @property
@@ -96,8 +113,6 @@ class RoundObserver:
         out.update({
             "dt_s": float(dt),
             "step_flops": flops,
-            "mfu": mfu(flops, dt, n_devices=self.n_devices,
-                       peak_flops=self.peak_flops),
             "samples_per_s": samples / dt if dt > 0 else 0.0,
             "wire_bytes_device": per_dev,
             "wire_bytes_round": per_dev * float(n_part) + float(inj_bytes),

@@ -91,7 +91,7 @@ class RequestStream:
     # this tuple (a client's next arrival waits for the *drawn* prompt to
     # stream in, so long prompts are also rarer per unit time).  None keeps
     # the fixed-length stream — and its exact RNG draw sequence, which the
-    # perf-gate baselines pin.
+    # tests' fixed-length traces rely on.
     prompt_lens: Optional[Sequence[int]] = None
     # shared-prefix trace mode (``assign_templates``): n_templates > 0 tags
     # each request with a Zipf-reused template whose first

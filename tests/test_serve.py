@@ -240,17 +240,25 @@ def test_static_waits_to_fill_and_blocks():
     assert s["completed"] == 2 and s["dropped"] == 0
 
 
-def test_continuous_beats_static_on_ttft_and_goodput():
-    stream = RequestStream(dist="S1", n_clients=8, prompt_len=16,
-                           max_new_tokens=8, slo_ttft_s=0.15, seed=0)
-    reqs = stream.generate(horizon_s=5.0)
-    cr, _ = ContinuousBatchingServer(4, COST).run(reqs)
-    sr, _ = StaticBatchingServer(4, COST).run(reqs)
+@pytest.mark.parametrize("stream_kw,horizon_s,cost", [
+    (dict(dist="S1", n_clients=8, prompt_len=16, max_new_tokens=8,
+          slo_ttft_s=0.15, seed=0), 5.0, COST),
+    # S2 near overload: the static batcher's fill waits blow the TTFT SLO
+    (dict(dist="S2", n_clients=12, prompt_len=64, max_new_tokens=16,
+          slo_ttft_s=0.25, slo_tpot_s=0.05, seed=0), 8.0,
+     StepCostModel(decode_step_s=0.01, prefill_token_s=5e-4)),
+], ids=["S1", "S2-near-overload"])
+def test_continuous_beats_static_on_ttft_and_goodput(stream_kw, horizon_s,
+                                                     cost):
+    reqs = RequestStream(**stream_kw).generate(horizon_s=horizon_s)
+    cr, _ = ContinuousBatchingServer(4, cost).run(reqs)
+    sr, _ = StaticBatchingServer(4, cost).run(reqs)
     h = max(max((r.finish_s or r.arrival_s) for r in cr),
             max((r.finish_s or r.arrival_s) for r in sr))
     cs, ss = summarize(cr, h), summarize(sr, h)
     assert cs["ttft_p99_s"] < ss["ttft_p99_s"]
     assert cs["goodput_tok_s"] > ss["goodput_tok_s"]
+    assert cs["slo_attainment"] > ss["slo_attainment"]
 
 
 def test_request_stream_reproducible_and_deadlined():

@@ -28,8 +28,9 @@ CTX = RunCtx(remat=False, chunk_q=8, chunk_k=8, loss_chunk=8)
 # one representative per cache family: dense KV, SWA ring, RG-LRU, xLSTM
 FAMILIES = ["qwen2-0.5b", "mixtral-8x22b", "recurrentgemma-2b", "xlstm-125m"]
 
-# the stress cost model the perf gate pins (decode 10ms, 0.5ms/token prefill
-# + 2ms dispatch base so chunk granularity has a real cost side)
+# the stress cost model of benchmarks/serving_scale.py (decode 10ms,
+# 0.5ms/token prefill + 2ms dispatch base so chunk granularity has a real
+# cost side)
 COST = StepCostModel(decode_step_s=0.01, prefill_token_s=5e-4,
                      prefill_base_s=2e-3)
 
@@ -209,8 +210,8 @@ def test_scheduler_conservation_across_grid():
 
 def test_chunked_interleaved_beats_whole_prompt():
     """Near overload with mixed prompt lengths: chunked prefill must win on
-    deadline-met goodput AND the TTFT tail (the perf gate pins the exact
-    values; this is the structural claim)."""
+    deadline-met goodput AND the TTFT tail (sim-clock figures: the claim is
+    the ordering, not the values)."""
     reqs = _s2_requests()
     _, whole = ContinuousBatchingServer(4, COST).run(reqs, horizon_s=8.0)
     _, chunked = Scheduler(4, COST, chunk_tokens=64,
@@ -571,7 +572,7 @@ def test_shared_prefix_sim_cell_wins():
                          chunk_tokens=16).run(reqs, horizon_s=4.0)
         assert s["conservation_ok"] and runner.pool.conserved()
         out[mode] = s
-    assert out[True]["goodput_tok_s"] >= out[False]["goodput_tok_s"]
+    assert out[True]["goodput_tok_s"] > out[False]["goodput_tok_s"]
     share = out[True]["prefix_sharing"]
     assert share["prefix_hit_rate"] > 0 and share["pages_saved_frac"] > 0
     assert "prefix_sharing" not in out[False]
